@@ -1,8 +1,9 @@
-"""Paper-scale example model (~100M): the kind of dynamic NLP model ORLOJ
-serves (GPT/BART class, Table 1).  Used by the end-to-end examples, the
-real-execution serving engine, and the engine-substrate eval tier
-(``repro.eval.substrate`` registers it as ``orloj_gpt``, served at
-``CONFIG.reduced()`` toy sizes so engine cells run on CPU)."""
+"""Paper-scale example model (134M params): the kind of dynamic NLP model
+ORLOJ serves (GPT/BART class, Table 1).  ``chip_smoke.py`` and
+``python -m repro.launch.serve`` serve it at this full width on one TPU
+chip.  The engine-substrate eval tier (``repro.eval.substrate`` registers
+it as ``orloj_gpt``) serves ``CONFIG.reduced()`` toy sizes, so that its
+engine cells also run on a host CPU."""
 from ..models.config import ModelConfig
 
 # Bucket/batch grid the serving examples and the paper-size engine profile

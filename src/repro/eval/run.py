@@ -49,6 +49,11 @@ def main(argv: list[str] | None = None) -> int:
     args = ap.parse_args(argv)
 
     specs = GRIDS[args.grid]()
+    if any(s.substrate != "sim" for s in specs):
+        # Imported here: sim-only grids stay JAX-free.
+        from ..compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     t0 = time.time()  # simlint: ignore[R1] -- CLI progress banner, reporting only
     print(f"# grid {args.grid}: {len(specs)} cells, jobs={args.jobs or 'auto'}",
           file=sys.stderr, flush=True)

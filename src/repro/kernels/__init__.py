@@ -2,8 +2,9 @@
 
 Each kernel ships three artifacts:
 - ``<name>.py`` — the ``pl.pallas_call`` kernel with explicit BlockSpec
-  VMEM tiling (TPU is the *target*; on this CPU container they are
-  validated in ``interpret=True`` mode);
+  VMEM tiling, compiled on a TPU backend and run in the Pallas interpreter
+  elsewhere (the CPU tests; ``tests/test_tpu_compile.py`` compiles them
+  for a described v5e chip);
 - ``ref.py``    — pure-jnp oracles;
 - ``ops.py``    — jit'd public wrappers with a ``use_pallas`` switch.
 

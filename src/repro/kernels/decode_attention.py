@@ -7,12 +7,15 @@ flash-decoding adapted to the TPU memory hierarchy (the cache streams
 HBM→VMEM; the group matmul feeds the MXU).
 
 ``valid_len`` masks unwritten cache slots (the serving engine's ring
-buffer / partially-filled cache).
+buffer / partially-filled cache).  It is a scalar-prefetch operand: it sits
+in SMEM and each grid step reads its row's length by ``program_id(0)``,
+since a ``(1, 1)`` VMEM block of a ``(B, 1)`` array breaks the TPU's
+(8, 128) block-tiling rule for every B > 1.
 
-Cache lengths need not be multiples of ``block_k``: the block size is
-rounded down to the largest divisor of ``S`` not exceeding the requested
-one, so any cache length is served (at reduced streaming efficiency when
-``S`` has no large divisor — keep caches multiples of 128 for the MXU).
+Cache lengths need not be multiples of ``block_k``: the cache is padded up
+to one, and ``valid_len`` masks the padding.  Every K/V tile is then
+``block_k`` rows, a multiple of 8 as the TPU's tiling requires.  The pad is
+a copy of the cache, so keep caches multiples of ``block_k``.
 """
 
 from __future__ import annotations
@@ -24,6 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -57,7 +62,7 @@ def _kernel(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * sm_scale  # (g, bk)
     kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = kpos < valid_ref[0, 0]
+    mask = kpos < valid_ref[pl.program_id(0)]
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_ref[:, 0]
@@ -88,46 +93,51 @@ def decode_attention_pallas(
 ) -> jax.Array:
     """q: (B, H, hd); k/v_cache: (B, KV, S, hd); valid_len: (B,) int32.
 
-    ``interpret=None`` (default) auto-detects: compiled on TPU, Pallas
-    interpreter elsewhere.  Pass True/False to force either mode (tests
-    pin the interpreter for determinism off-accelerator)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    ``interpret=None`` (default) runs the compiled kernel on a TPU backend
+    and the Pallas interpreter elsewhere; True/False forces either mode."""
     b, h, hd = q.shape
     kv, s = k_cache.shape[1], k_cache.shape[2]
     assert h % kv == 0
     g = h // kv
     if s <= 0:
         raise ValueError(f"cache length must be positive, got S={s}")
-    block_k = min(block_k, s)
-    # Largest divisor of S not exceeding the requested block size: keeps
-    # the grid exact (no partially-out-of-bounds cache tiles) for caches
-    # whose length is not a multiple of block_k, e.g. S=300 @ bk=256.
-    while s % block_k:
-        block_k -= 1
-    n_k = s // block_k
+    block_k = min(block_k, -(-s // 8) * 8)
+    if block_k % 8:
+        raise ValueError(f"block_k must be a multiple of 8, got {block_k}")
+    pad = (-s) % block_k
+    if pad:
+        padcfg = ((0, 0), (0, 0), (0, pad), (0, 0))
+        k_cache, v_cache = jnp.pad(k_cache, padcfg), jnp.pad(v_cache, padcfg)
+    n_k = (s + pad) // block_k
     qg = q.reshape(b, kv, g, hd)
-    valid2d = valid_len.reshape(b, 1).astype(jnp.int32)
 
     kernel = functools.partial(
         _kernel, sm_scale=1.0 / np.sqrt(hd), block_k=block_k, n_k=n_k
     )
     out = pl.pallas_call(
         kernel,
-        grid=(b, kv, n_k),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bi, ci, ki: (bi, 0)),
-            pl.BlockSpec((1, 1, g, hd), lambda bi, ci, ki: (bi, ci, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda bi, ci, ki: (bi, ci, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda bi, ci, ki: (bi, ci, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda bi, ci, ki: (bi, ci, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kv, n_k),
+            in_specs=[
+                pl.BlockSpec((1, 1, g, hd), lambda bi, ci, ki, vl: (bi, ci, 0, 0)),
+                pl.BlockSpec(
+                    (1, 1, block_k, hd), lambda bi, ci, ki, vl: (bi, ci, ki, 0)
+                ),
+                pl.BlockSpec(
+                    (1, 1, block_k, hd), lambda bi, ci, ki, vl: (bi, ci, ki, 0)
+                ),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, g, hd), lambda bi, ci, ki, vl: (bi, ci, 0, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((g, hd), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, hd), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(valid2d, qg, k_cache, v_cache)
+        interpret=resolve_interpret(interpret),
+    )(valid_len.astype(jnp.int32), qg, k_cache, v_cache)
     return out.reshape(b, h, hd)

@@ -7,7 +7,8 @@ flash-attention recurrence re-tiled for the MXU (128-aligned tiles).
 
 Per-request ``lengths`` implement the padded-batch execution model the
 ORLOJ scheduler reasons about: all requests run at the batch's padded
-length (Eq. 3–4), the mask keeps short requests numerically exact.
+length (Eq. 3–4), the mask keeps short requests numerically exact.  They
+are a scalar-prefetch operand in SMEM, read by ``program_id(0)``.
 
 Supports causal masking, GQA (KV heads < Q heads) and sliding windows.
 """
@@ -21,6 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -60,7 +63,7 @@ def _kernel(
 
     qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    mask = kpos < lengths_ref[0, 0]
+    mask = kpos < lengths_ref[pl.program_id(0)]
     if causal:
         mask &= kpos <= qpos
     if window > 0:
@@ -95,9 +98,12 @@ def flash_attention_pallas(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """q: (B, H, S, hd); k, v: (B, KV, S, hd); lengths: (B,) int32."""
+    """q: (B, H, S, hd); k, v: (B, KV, S, hd); lengths: (B,) int32.
+
+    ``interpret=None`` (default) runs the compiled kernel on a TPU backend
+    and the Pallas interpreter elsewhere; True/False forces either mode."""
     b, h, s, hd = q.shape
     kv = k.shape[1]
     assert h % kv == 0
@@ -105,9 +111,7 @@ def flash_attention_pallas(
     block_k = min(block_k, s)
     assert s % block_q == 0 and s % block_k == 0, (s, block_q, block_k)
     n_q, n_k = s // block_q, s // block_k
-    grid = (b, h, n_q, n_k)
     qpk = h // kv
-    lengths2d = lengths.reshape(b, 1).astype(jnp.int32)
 
     kernel = functools.partial(
         _kernel,
@@ -120,27 +124,31 @@ def flash_attention_pallas(
     )
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bi, hi, qi, ki: (bi, 0)),  # lengths
-            pl.BlockSpec(
-                (1, 1, block_q, hd), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h, n_q, n_k),
+            in_specs=[
+                pl.BlockSpec(
+                    (1, 1, block_q, hd), lambda bi, hi, qi, ki, ln: (bi, hi, qi, 0)
+                ),
+                pl.BlockSpec(
+                    (1, 1, block_k, hd),
+                    lambda bi, hi, qi, ki, ln: (bi, hi // qpk, ki, 0),
+                ),
+                pl.BlockSpec(
+                    (1, 1, block_k, hd),
+                    lambda bi, hi, qi, ki, ln: (bi, hi // qpk, ki, 0),
+                ),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, block_q, hd), lambda bi, hi, qi, ki, ln: (bi, hi, qi, 0)
             ),
-            pl.BlockSpec(
-                (1, 1, block_k, hd), lambda bi, hi, qi, ki: (bi, hi // qpk, ki, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, block_k, hd), lambda bi, hi, qi, ki: (bi, hi // qpk, ki, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, hd), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
+            scratch_shapes=[
+                pltpu.VMEM((block_q, hd), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, hd), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lengths2d, q, k, v)
+        interpret=resolve_interpret(interpret),
+    )(lengths.astype(jnp.int32), q, k, v)

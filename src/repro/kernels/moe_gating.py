@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import resolve_interpret
+
 
 def _kernel(logits_ref, gates_ref, idx_ref, *, top_k: int):
     x = logits_ref[...].astype(jnp.float32)  # (bt, E)
@@ -46,7 +48,7 @@ def moe_gating_pallas(
     top_k: int,
     *,
     block_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """logits: (T, E) → (gates (T, k) f32, idx (T, k) int32)."""
     t, e = logits.shape
@@ -64,5 +66,5 @@ def moe_gating_pallas(
             jax.ShapeDtypeStruct((t, top_k), jnp.float32),
             jax.ShapeDtypeStruct((t, top_k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(logits)

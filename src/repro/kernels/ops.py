@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``use_pallas=True`` runs the Pallas kernel (interpret mode on CPU; compiled
-on a real TPU where ``interpret=False`` is passed through); ``False`` runs
-the pure-jnp oracle — the wrappers keep signatures identical so the model
-layer can switch per deployment.
+``use_pallas=True`` runs the Pallas kernel: compiled on a TPU backend, in
+the Pallas interpreter on any other (decided when the wrapper is traced,
+see :mod:`.backend`); ``False`` runs the pure-jnp oracle — the wrappers
+keep signatures identical so the model layer can switch per deployment.
 """
 
 from __future__ import annotations
@@ -18,8 +18,6 @@ from .decode_attention import decode_attention_pallas
 from .flash_attention import flash_attention_pallas
 from .moe_gating import moe_gating_pallas
 from .rmsnorm import rmsnorm_pallas
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 @functools.partial(
@@ -63,7 +61,6 @@ def flash_attention(
         window=window,
         block_q=block_q,
         block_k=block_k,
-        interpret=not _ON_TPU,
     )
     return out[:, :, :s] if pad else out
 
@@ -74,7 +71,7 @@ def decode_attention(q, k_cache, v_cache, valid_len, *, use_pallas: bool = True,
     if not use_pallas:
         return ref.decode_attention_ref(q, k_cache, v_cache, valid_len)
     return decode_attention_pallas(
-        q, k_cache, v_cache, valid_len, block_k=block_k, interpret=not _ON_TPU
+        q, k_cache, v_cache, valid_len, block_k=block_k
     )
 
 
@@ -86,7 +83,7 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, use_pallas: bool = True):
     if not use_pallas:
         out = ref.rmsnorm_ref(x2, scale, eps)
     else:
-        out = rmsnorm_pallas(x2, scale, eps=eps, interpret=not _ON_TPU)
+        out = rmsnorm_pallas(x2, scale, eps=eps)
     return out.reshape(shape)
 
 
@@ -95,4 +92,4 @@ def moe_gating(logits, top_k: int, *, use_pallas: bool = True):
     """logits: (T, E) → (gates (T,k), idx (T,k))."""
     if not use_pallas:
         return ref.moe_gating_ref(logits, top_k)
-    return moe_gating_pallas(logits, top_k, interpret=not _ON_TPU)
+    return moe_gating_pallas(logits, top_k)

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import resolve_interpret
+
 
 def _kernel(x_ref, scale_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -27,7 +29,7 @@ def rmsnorm_pallas(
     *,
     eps: float = 1e-6,
     block_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """x: (T, d); scale: (d,)."""
     t, d = x.shape
@@ -42,5 +44,5 @@ def rmsnorm_pallas(
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, scale)

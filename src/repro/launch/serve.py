@@ -23,8 +23,17 @@ from ..core import (
     OrlojScheduler,
     SchedulerConfig,
 )
+from ..compile_cache import enable_compile_cache
 from ..configs import get_config
 from ..serving.engine import EngineConfig, ServingEngine
+
+
+def bimodal_length(rng: np.random.Generator) -> int:
+    """Prompt length in tokens: chat-style short prompts (70%) and long
+    documents (30%), capped at the largest serving bucket (256)."""
+    if rng.random() < 0.7:
+        return int(np.clip(rng.normal(40, 12), 4, 256))
+    return int(np.clip(rng.normal(200, 30), 4, 256))
 
 
 def make_scheduler(name: str, lm, hist, batch_sizes):
@@ -61,6 +70,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    print(f"compile cache: {enable_compile_cache()}")
     cfg = get_config(args.arch)
     if cfg.n_params_estimate > 500e6:
         cfg = cfg.reduced(vocab_size=min(cfg.vocab_size, 8192))
@@ -69,12 +79,6 @@ def main() -> None:
     print(f"profiling {cfg.name} latency curve ...")
     lm = engine.profile_latency_model()
     print(f"Eq.3 fit: c0={lm.c0:.2f} ms, c1={lm.c1*1e3:.3f} ms/ktok")
-
-    # Bimodal length distribution: chat-style short prompts + long documents.
-    def length_sampler(rng):
-        if rng.random() < 0.7:
-            return int(np.clip(rng.normal(40, 12), 4, 256))
-        return int(np.clip(rng.normal(200, 30), 4, 256))
 
     names = (
         ["orloj", "clockwork", "nexus", "clipper"]
@@ -85,7 +89,7 @@ def main() -> None:
         reqs, hist = engine.make_requests(
             args.n,
             lm,
-            length_sampler=length_sampler,
+            length_sampler=bimodal_length,
             slo_scale=args.slo_scale,
             utilization=args.utilization,
             seed=args.seed,

@@ -1,7 +1,8 @@
 """Real-execution serving engine: ORLOJ scheduling over actual JAX model
 inference with measured wall-clock execution times.
 
-This is the paper's full loop running for real on CPU-jitted models:
+This is the paper's full loop running for real on jitted models, on
+whatever backend JAX has (a TPU chip, or the host CPU in the tests):
 variable-length requests → Orloj (or baseline) scheduler → padded batch
 (bucketed static shapes, one compiled program per bucket) → measured
 execution feeds the online profiler.  Time is *hybrid*: the clock advances
@@ -56,13 +57,20 @@ class JaxExecutor:
     are *not* logged.  The log is a bounded ring (:data:`MEASURED_LOG_CAP`
     most recent batches) so callers that never read it — long-running
     serving processes, the examples — cannot leak memory; use
-    :meth:`drain_measured` to read-and-reset it around one serving run."""
+    :meth:`drain_measured` to read-and-reset it around one serving run.
+
+    With ``device`` set, the executor holds its own copy of the params on
+    that device and runs every batch there (one replica per chip); without
+    it, arrays go to JAX's default device."""
 
     MEASURED_LOG_CAP = 4096
 
-    def __init__(self, model: Model, params, cfg: EngineConfig):
+    def __init__(
+        self, model: Model, params, cfg: EngineConfig, device: jax.Device | None = None
+    ):
         self.model = model
-        self.params = params
+        self.device = device
+        self.params = params if device is None else jax.device_put(params, device)
         self.cfg = cfg
         self._fwd = jax.jit(
             lambda p, batch: self.model.logits(p, batch),
@@ -94,7 +102,7 @@ class JaxExecutor:
                 [tokens, np.zeros((k - tokens.shape[0],) + tokens.shape[1:], tokens.dtype)]
             )
         key = tokens.shape
-        batch = {"tokens": jnp.asarray(tokens)}
+        batch = {"tokens": jax.device_put(tokens, self.device)}
         if key not in self._compiled:
             # warm the cache so compile time never pollutes a measurement
             jax.block_until_ready(self._fwd(self.params, batch))
@@ -138,11 +146,11 @@ class DecodeJaxExecutor:
     (query vectors, cache contents, prompt token ids) are seeded
     synthetic — this executor prices the attention decode step, it does
     not generate text, and it deliberately omits the MLP/sampling cost
-    of a full model step.  On CPU hosts the Pallas kernel only runs
-    under the (very slow) interpreter, so ``use_pallas=None`` follows
-    the kernel-level auto-detect: compiled Pallas on TPU, the jnp
-    reference oracle elsewhere — the same numerics, honestly timed on
-    what the host can actually run.  Prompts longer than the largest
+    of a full model step.  ``use_pallas=None`` picks the compiled Pallas
+    kernel on a TPU backend and the jnp reference oracle elsewhere (off
+    the TPU the kernel would only run in the very slow Pallas
+    interpreter) — the same numerics, timed on what the backend actually
+    runs.  Prompts longer than the largest
     prefill bucket are served but their cache entry is truncated to
     ``max_cache`` (a ring buffer keeps the most recent positions)."""
 
@@ -212,6 +220,18 @@ class DecodeJaxExecutor:
             q, kc2, vc2, valid2, use_pallas=use_pallas, block_k=block_k
         )
         return kc2, vc2, valid2, out
+
+    def lower_step(self) -> jax.stages.Lowered:
+        """The decode step lowered at this executor's shapes, for inspecting
+        the program (a compiled Pallas kernel shows as ``tpu_custom_call``)."""
+        b, h, kv, hd = self.max_batch, self.n_heads, self.n_kv, self.head_dim
+        return self._step.lower(
+            self._kc, self._vc, self._valid, self._valid > 0,
+            jnp.zeros((b, h, hd), jnp.float32),
+            jnp.zeros((b, kv, hd), jnp.float32),
+            jnp.zeros((b, kv, hd), jnp.float32),
+            use_pallas=self.use_pallas, block_k=self.block_k,
+        )
 
     def _decode_once(self) -> float:
         """One measured decode step at full capacity (ms); mutates the
@@ -338,16 +358,26 @@ class ServingEngine:
         self.model = Model(model_cfg)
         self.params = self.model.init(jax.random.PRNGKey(seed))
         self.executor = JaxExecutor(self.model, self.params, self.cfg)
+        self._device_executors: dict[jax.Device, JaxExecutor] = {}
 
-    def executor_for(self, scale: float = 1.0) -> JaxExecutor | _ScaledExecutor:
+    def executor_for(
+        self, scale: float = 1.0, device: jax.Device | None = None
+    ) -> JaxExecutor | _ScaledExecutor:
         """Executor factory for pool construction: ``scale == 1`` returns
         the shared measured executor; ``scale > 1`` wraps it so the replica
-        appears ``scale``× slower (heterogeneous pools, one real backend)."""
-        if scale == 1.0:
-            return self.executor
+        appears ``scale``× slower (heterogeneous pools, one real backend).
+        With ``device`` set, the executor is this engine's replica on that
+        device (built once per device, with its own copy of the params)."""
         if scale <= 0.0:
             raise ValueError(f"executor scale must be positive, got {scale}")
-        return _ScaledExecutor(self.executor, scale)
+        ex = self.executor
+        if device is not None:
+            if device not in self._device_executors:
+                self._device_executors[device] = JaxExecutor(
+                    self.model, self.params, self.cfg, device
+                )
+            ex = self._device_executors[device]
+        return ex if scale == 1.0 else _ScaledExecutor(ex, scale)
 
     # -------------------------------------------------------- profiling
     def profile_latency_model(self) -> BatchLatencyModel:
@@ -532,7 +562,8 @@ class ServingEngine:
         By default all replicas share this engine's measured JAX executor
         (one physical backend timed once per batch); pass ``executors``
         (one per scheduler, e.g. from :meth:`executor_for`) to build a
-        heterogeneous pool of fast and scaled-slow replicas.  The front-end
+        heterogeneous pool of fast and scaled-slow replicas, or a pool
+        with one replica per device.  The front-end
         ``policy`` assigns arrivals to replicas."""
         if executors is None:
             executors = [self.executor] * len(schedulers)

@@ -1,11 +1,8 @@
 """Tests for empirical distributions and order statistics (paper §4.2)."""
 
 import numpy as np
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ModuleNotFoundError:  # bare env: property tests skip, the rest run
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.distributions import (
     BatchLatencyModel,

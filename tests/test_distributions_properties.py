@@ -20,11 +20,8 @@ is installed (example-based pins always run):
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ModuleNotFoundError:  # bare env: property tests skip, the rest run
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.distributions import EmpiricalDistribution
 

@@ -82,6 +82,64 @@ def test_pool_serving_real_execution(engine):
     assert res.utilization <= 1.0 + 1e-9
 
 
+def test_executor_for_device_holds_its_own_params(engine):
+    """A replica bound to a device keeps its own copy of the params there,
+    runs its batches there, and is built once per device."""
+    import jax
+
+    dev = jax.devices()[0]
+    ex = engine.executor_for(device=dev)
+    assert ex is not engine.executor
+    assert engine.executor_for(device=dev) is ex
+    assert all(x.devices() == {dev} for x in jax.tree.leaves(ex.params))
+    ms, k_pad = ex._run(np.ones((2, 16), np.int32))
+    assert k_pad == 2 and ms > 0.0
+    slow = engine.executor_for(2.0, device=dev)
+    assert slow.inner is ex and slow.scale == 2.0
+
+
+POOL_ON_DEVICES = """
+import jax, numpy as np
+from repro.core import OrlojScheduler, SchedulerConfig
+from repro.serving.engine import EngineConfig, ServingEngine
+from test_engine import TINY
+
+devs = jax.devices()
+assert len(devs) == 2, devs
+engine = ServingEngine(TINY, EngineConfig(buckets=(16, 32), batch_sizes=(1, 2), profile_reps=1))
+lm = engine.profile_latency_model()
+reqs, _ = engine.make_requests(
+    16, lm, length_sampler=lambda rng: int(rng.integers(4, 32)),
+    slo_scale=50.0, utilization=1.0, seed=3,
+)
+execs = [engine.executor_for(device=d) for d in devs]
+scheds = [OrlojScheduler(lm, cfg=SchedulerConfig(batch_sizes=(1, 2))) for _ in devs]
+res = engine.serve_pool(reqs, scheds, executors=execs)
+assert res.n_total == 16 and res.conserved, res.summary()
+batches = [len(ex.drain_measured()) for ex in execs]
+assert sum(batches) == res.n_batches and min(batches) > 0, batches
+"""
+
+
+def test_serve_pool_one_replica_per_device():
+    """Each replica of a pool runs its batches on its own device (two
+    virtual CPU devices, in a child process so the flag takes effect)."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    env = {
+        **os.environ,
+        "JAX_PLATFORMS": "cpu",
+        "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
+        "PYTHONPATH": os.pathsep.join([str(Path(__file__).parent), *sys.path]),
+    }
+    subprocess.run(
+        [sys.executable, "-c", POOL_ON_DEVICES], check=True, env=env, timeout=300
+    )
+
+
 def test_serve_real_requests_end_to_end(engine):
     lm = engine.profile_latency_model()
     reqs, hist = engine.make_requests(
@@ -150,6 +208,14 @@ def test_serve_tokens_rejects_oversized_scheduler(engine):
         engine.serve_tokens(
             [], FcfsTokenScheduler(TokenSchedConfig(max_batch=8)), dec
         )
+
+
+def test_decode_lower_step_has_the_executor_shapes(engine):
+    dec = engine.decode_executor(max_batch=2, max_cache=32)
+    kc, vc, valid, out = dec.lower_step().out_info
+    assert kc.shape == vc.shape == (2, dec.n_kv, 32, dec.head_dim)
+    assert valid.shape == (2,)
+    assert out.shape == (2, dec.n_heads, dec.head_dim)
 
 
 def test_decode_executor_pallas_interpreter_agrees(engine):

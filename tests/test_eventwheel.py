@@ -9,11 +9,8 @@ import math
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ModuleNotFoundError:  # bare env: property tests skip, the rest run
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.eventwheel import MAX_BUCKET_SPAN, EventWheel
 from repro.core.request import Request

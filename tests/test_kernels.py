@@ -1,12 +1,17 @@
 """Pallas kernel validation: sweep shapes/dtypes, assert_allclose against
 the pure-jnp oracles (interpret=True executes the kernel body on CPU)."""
 
+import os
+import subprocess
+import sys
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import decode_attention, flash_attention, moe_gating, rmsnorm
 from repro.kernels import ref
+from repro.kernels.decode_attention import decode_attention_pallas
 
 
 def _tol(dtype):
@@ -109,10 +114,10 @@ def test_decode_attention_shapes(b, h, kv, s, hd, dtype):
 @pytest.mark.parametrize(
     "s,block_k",
     [
-        (300, 256),   # S % bk != 0: bk rounds down to a divisor (150)
-        (96, 64),     # rounds 64 -> 48
-        (7, 256),     # S prime and < bk: degenerates to bk=7
-        (130, 128),   # 130 = 2*5*13: largest divisor <= 128 is 65
+        (300, 256),   # S % bk != 0: the cache pads to 512, two tiles
+        (96, 64),     # pads to 128
+        (7, 256),     # S < bk: bk clamps to 8, the cache pads to 8
+        (130, 128),   # pads to 256, the second tile holds 2 live rows
     ],
 )
 def test_decode_attention_nondivisible_cache_length(s, block_k):
@@ -129,6 +134,47 @@ def test_decode_attention_nondivisible_cache_length(s, block_k):
         np.asarray(out, np.float32), np.asarray(want, np.float32),
         rtol=2e-5, atol=2e-5,
     )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_pallas_pads_cache_in_interpreter(dtype):
+    """The kernel itself (interpret mode) at a cache length that is not a
+    multiple of block_k: the padded tail is masked, including for rows
+    whose valid length reaches the last real slot."""
+    rng = np.random.default_rng(11)
+    b, h, kv, s, hd = 4, 8, 2, 300, 64
+    q = jnp.asarray(rng.normal(size=(b, h, hd)), dtype)
+    kc = jnp.asarray(rng.normal(size=(b, kv, s, hd)), dtype)
+    vc = jnp.asarray(rng.normal(size=(b, kv, s, hd)), dtype)
+    valid = jnp.array([s, 1, 257, 0], jnp.int32)
+    out = decode_attention_pallas(q, kc, vc, valid, block_k=256, interpret=True)
+    want = ref.decode_attention_ref(q, kc, vc, valid)
+    assert out.shape == (b, h, hd)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(want, np.float32), **_tol(dtype)
+    )
+
+
+def test_decode_attention_rejects_unaligned_block():
+    q = jnp.zeros((1, 2, 64), jnp.float32)
+    kc = jnp.zeros((1, 2, 64, 64), jnp.float32)
+    with pytest.raises(ValueError, match="multiple of 8"):
+        decode_attention_pallas(
+            q, kc, kc, jnp.array([3], jnp.int32), block_k=12, interpret=True
+        )
+
+
+def test_importing_kernels_initialises_no_backend():
+    """Interpret-vs-compiled is decided when a kernel is traced: importing
+    the kernels must not start a backend (on a TPU host that would take
+    the chip)."""
+    code = (
+        "import repro.kernels, repro.serving.engine\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge._backends, list(xla_bridge._backends)\n"
+    )
+    env = {**os.environ, "PYTHONPATH": os.pathsep.join(sys.path)}
+    subprocess.run([sys.executable, "-c", code], check=True, env=env, timeout=120)
 
 
 def test_decode_attention_empty_rows():
