@@ -349,6 +349,9 @@ class SimResult:
     n_model_loads: int = 0
     n_model_evicts: int = 0
     model_load_ms: float = 0.0
+    # Scoring lines (request x batch size) the pool's schedulers counted
+    # in their ``n_scored`` during the run; 0 for schedulers without it.
+    n_scored: int = 0
 
     @property
     def conserved(self) -> bool:
@@ -715,6 +718,7 @@ def run_event_loop(
             wall_budget_s=wall_budget_s,
         )
 
+    scored0 = _n_scored(workers)
     requests = sorted(requests, key=lambda r: r.release)
     events: list[tuple[float, int, int, object]] = []
     seq = itertools.count()
@@ -1130,7 +1134,15 @@ def run_event_loop(
         n_model_loads=res.n_loads if res is not None else 0,
         n_model_evicts=res.n_evicts if res is not None else 0,
         model_load_ms=res.load_ms_total if res is not None else 0.0,
+        n_scored=_n_scored(workers) - scored0,
     )
+
+
+def _n_scored(workers: Sequence[Worker]) -> int:
+    """The ``n_scored`` counters of the pool's schedulers, each scheduler
+    once (0 for a scheduler without the counter)."""
+    scheds = {id(w.scheduler): w.scheduler for w in workers}
+    return sum(getattr(s, "n_scored", 0) for s in scheds.values())
 
 
 def _wheel_width(group_times: Sequence[float]) -> float | None:
@@ -1186,6 +1198,7 @@ def _array_loop(
     no longer exists.
     """
     n = len(workers)
+    scored0 = _n_scored(workers)
     store = RequestStore(requests)
     reqs = store.requests
     gstarts = store.group_starts
@@ -1793,6 +1806,7 @@ def _array_loop(
         n_model_loads=res.n_loads if res is not None else 0,
         n_model_evicts=res.n_evicts if res is not None else 0,
         model_load_ms=res.load_ms_total if res is not None else 0.0,
+        n_scored=_n_scored(workers) - scored0,
     )
 
 

@@ -30,6 +30,7 @@ from typing import Sequence
 
 import numpy as np
 
+from ..tracing import span
 from .distributions import (
     BatchLatencyModel,
     EmpiricalDistribution,
@@ -178,6 +179,9 @@ class OrlojScheduler:
         self._app_bs_est: dict[tuple[str, int], float] = {}
         self._default_dist = EmpiricalDistribution.delta(10.0)
         self.n_timed_out = 0
+        # (request, batch size) lines scored, on arrival, on a milestone
+        # re-score and on a full recompute
+        self.n_scored = 0
         self._rebuild_models()
 
     # ------------------------------------------------------------------
@@ -212,15 +216,16 @@ class OrlojScheduler:
         is the heavy computation moved off the critical path).  One snapshot
         swap costs one mixture evaluation on the cached grid plus one CDF
         power + hull-ready score model per batch size."""
-        mix = self._mixture()
-        self._mix = mix
-        self._app_bs_est.clear()
-        self._iid_max_cache: dict[int, EmpiricalDistribution] = {1: mix}
-        for bs, st in self._bs_state.items():
-            max_dist = self._iid_max_mix(bs)
-            batch_dist = self.latency_model.batch_dist(max_dist, bs)
-            st.score_model = BinScoreModel(batch_dist, b=self.cfg.b)
-            st.est_latency = self.latency_model.expected_batch_time(mix, bs)
+        with span("orloj.sched.rebuild"):
+            mix = self._mixture()
+            self._mix = mix
+            self._app_bs_est.clear()
+            self._iid_max_cache: dict[int, EmpiricalDistribution] = {1: mix}
+            for bs, st in self._bs_state.items():
+                max_dist = self._iid_max_mix(bs)
+                batch_dist = self.latency_model.batch_dist(max_dist, bs)
+                st.score_model = BinScoreModel(batch_dist, b=self.cfg.b)
+                st.est_latency = self.latency_model.expected_batch_time(mix, bs)
 
     def estimate_batch_latency(self, req: Request, bs: int) -> float:
         """EstimateBatchLatency(r, bs) — Algorithm 1 line 11."""
@@ -256,28 +261,30 @@ class OrlojScheduler:
         vectorized Eq.-2 pass per batch size and insert the new lines as a
         single hull block (the event loop coalesces same-timestamp
         arrivals into one call)."""
-        reqs = list(reqs)
-        if not reqs:
-            return
-        deadlines, costs, seg_starts = _flatten_steps(reqs)
-        rids = [r.rid for r in reqs]
-        all_bs = set(self._bs_state)
-        for req, rid in zip(reqs, rids):
-            self._pending[rid] = req
-            # simlint: ignore[R5] -- per-request feasibility state is the data structure itself, not transient churn; the drop phase mutates it per batch size
-            self._feasible[rid] = set(all_bs)
-        heap_entries = [(r.release + r.slo, r.rid) for r in reqs]
-        for bs, st in self._bs_state.items():
-            alpha, beta, miles = _score_flat(
-                st.score_model, deadlines, costs, seg_starts, now, self._base
-            )
-            # simlint: ignore[R5] -- one bulk hull-block load per batch size (not per request); this *is* the PR-2 vectorized path replacing n scalar inserts
-            st.hull.insert_many(list(zip(rids, alpha.tolist(), beta.tolist())))
-            for entry in heap_entries:
-                heapq.heappush(st.deadline_heap, entry)
-            for rid, m in zip(rids, miles.tolist()):
-                if math.isfinite(m):
-                    heapq.heappush(self._milestones, (m, rid, bs))
+        with span("orloj.sched.on_arrivals"):
+            reqs = list(reqs)
+            if not reqs:
+                return
+            deadlines, costs, seg_starts = _flatten_steps(reqs)
+            rids = [r.rid for r in reqs]
+            all_bs = set(self._bs_state)
+            for req, rid in zip(reqs, rids):
+                self._pending[rid] = req
+                # simlint: ignore[R5] -- per-request feasibility state is the data structure itself, not transient churn; the drop phase mutates it per batch size
+                self._feasible[rid] = set(all_bs)
+            heap_entries = [(r.release + r.slo, r.rid) for r in reqs]
+            self.n_scored += len(reqs) * len(self._bs_state)
+            for bs, st in self._bs_state.items():
+                alpha, beta, miles = _score_flat(
+                    st.score_model, deadlines, costs, seg_starts, now, self._base
+                )
+                # simlint: ignore[R5] -- one bulk hull-block load per batch size (not per request); this *is* the PR-2 vectorized path replacing n scalar inserts
+                st.hull.insert_many(list(zip(rids, alpha.tolist(), beta.tolist())))
+                for entry in heap_entries:
+                    heapq.heappush(st.deadline_heap, entry)
+                for rid, m in zip(rids, miles.tolist()):
+                    if math.isfinite(m):
+                        heapq.heappush(self._milestones, (m, rid, bs))
 
     def on_arrivals_cols(self, store, lo: int, hi: int, now: float) -> None:
         """Columnar bulk arrival: rows ``[lo, hi)`` of the array engine's
@@ -292,13 +299,14 @@ class OrlojScheduler:
         self, batch: Batch, now: float, alone_times_ms: Sequence[float]
     ) -> None:
         """Feedback: sampled finished requests go to the async profiler."""
-        for req, alone_ms in zip(batch.requests, alone_times_ms):
-            self.profiler.observe(req.app_id, alone_ms, now)
-        snap = self.profiler.maybe_pickup(now)
-        if snap:
-            self._app_dists = snap
-            self._rebuild_models()
-            self._recompute_all(now)
+        with span("orloj.sched.on_batch_done"):
+            for req, alone_ms in zip(batch.requests, alone_times_ms):
+                self.profiler.observe(req.app_id, alone_ms, now)
+            snap = self.profiler.maybe_pickup(now)
+            if snap:
+                self._app_dists = snap
+                self._rebuild_models()
+                self._recompute_all(now)
 
     # ------------------------------------------------------------------
     # Score maintenance (Algorithm 1 lines 1–9)
@@ -315,28 +323,30 @@ class OrlojScheduler:
         """Full (α, β) refresh (base reset, snapshot swap): one vectorized
         scoring pass per batch size + an O(n log n) hull bulk load, instead
         of O(pending · |bs|) scalar scores with cascading block merges."""
-        self._milestones.clear()
-        reqs = list(self._pending.values())
-        if not reqs:
-            for st in self._bs_state.values():
-                st.hull = HullQueue()
-            return
-        deadlines, costs, seg_starts = _flatten_steps(reqs)
-        rids = [r.rid for r in reqs]
-        for bs, st in self._bs_state.items():
-            alpha, beta, miles = _score_flat(
-                st.score_model, deadlines, costs, seg_starts, now, self._base
-            )
-            lines = []
-            for rid, a, b_, m in zip(
-                rids, alpha.tolist(), beta.tolist(), miles.tolist()
-            ):
-                if bs not in self._feasible[rid]:
-                    continue
-                lines.append((rid, a, b_))
-                if math.isfinite(m):
-                    heapq.heappush(self._milestones, (m, rid, bs))
-            st.hull.bulk_load(lines)
+        with span("orloj.sched.recompute"):
+            self._milestones.clear()
+            reqs = list(self._pending.values())
+            if not reqs:
+                for st in self._bs_state.values():
+                    st.hull = HullQueue()
+                return
+            deadlines, costs, seg_starts = _flatten_steps(reqs)
+            rids = [r.rid for r in reqs]
+            self.n_scored += len(reqs) * len(self._bs_state)
+            for bs, st in self._bs_state.items():
+                alpha, beta, miles = _score_flat(
+                    st.score_model, deadlines, costs, seg_starts, now, self._base
+                )
+                lines = []
+                for rid, a, b_, m in zip(
+                    rids, alpha.tolist(), beta.tolist(), miles.tolist()
+                ):
+                    if bs not in self._feasible[rid]:
+                        continue
+                    lines.append((rid, a, b_))
+                    if math.isfinite(m):
+                        heapq.heappush(self._milestones, (m, rid, bs))
+                st.hull.bulk_load(lines)
 
     def _update_due_scores(self, now: float) -> None:
         # Drain every due milestone first, then re-score the affected
@@ -344,48 +354,51 @@ class OrlojScheduler:
         # milestone is strictly in the future up to float rounding; the
         # `> now` guard below keeps an ulp-coincident one from re-entering
         # the heap at the same timestamp.
-        due: dict[int, set[int]] = {}
-        while self._milestones and self._milestones[0][0] <= now:
-            _, rid, bs = heapq.heappop(self._milestones)
-            if rid in self._pending and bs in self._feasible.get(rid, ()):
-                due.setdefault(bs, set()).add(rid)
-        for bs, rid_set in due.items():
-            st = self._bs_state[bs]
-            rids = sorted(rid_set)  # deterministic re-score order (R4)
-            reqs = [self._pending[rid] for rid in rids]
-            deadlines, costs, seg_starts = _flatten_steps(reqs)
-            alpha, beta, miles = _score_flat(
-                st.score_model, deadlines, costs, seg_starts, now, self._base
-            )
-            for rid, a, b_, m in zip(
-                rids, alpha.tolist(), beta.tolist(), miles.tolist()
-            ):
-                st.hull.update(rid, a, b_)
-                if math.isfinite(m) and m > now:
-                    heapq.heappush(self._milestones, (m, rid, bs))
+        with span("orloj.sched.rescore"):
+            due: dict[int, set[int]] = {}
+            while self._milestones and self._milestones[0][0] <= now:
+                _, rid, bs = heapq.heappop(self._milestones)
+                if rid in self._pending and bs in self._feasible.get(rid, ()):
+                    due.setdefault(bs, set()).add(rid)
+            for bs, rid_set in due.items():
+                st = self._bs_state[bs]
+                rids = sorted(rid_set)  # deterministic re-score order (R4)
+                reqs = [self._pending[rid] for rid in rids]
+                self.n_scored += len(rids)
+                deadlines, costs, seg_starts = _flatten_steps(reqs)
+                alpha, beta, miles = _score_flat(
+                    st.score_model, deadlines, costs, seg_starts, now, self._base
+                )
+                for rid, a, b_, m in zip(
+                    rids, alpha.tolist(), beta.tolist(), miles.tolist()
+                ):
+                    st.hull.update(rid, a, b_)
+                    if math.isfinite(m) and m > now:
+                        heapq.heappush(self._milestones, (m, rid, bs))
 
     # ------------------------------------------------------------------
     # Drop phase (Algorithm 1 lines 10–14)
     # ------------------------------------------------------------------
     def _drop_phase(self, now: float) -> None:
-        for bs, st in self._bs_state.items():
-            while st.deadline_heap:
-                deadline, rid = st.deadline_heap[0]
-                req = self._pending.get(rid)
-                if req is None or bs not in self._feasible.get(rid, ()):
-                    heapq.heappop(st.deadline_heap)  # lazy removal
-                    continue
-                est = self.estimate_batch_latency(req, bs) * self.cfg.drop_safety
-                if now + est > deadline:
-                    heapq.heappop(st.deadline_heap)
-                    st.hull.delete(rid)
-                    self._feasible[rid].discard(bs)
-                    if not self._feasible[rid]:  # line 13–14: timed out
-                        self._remove(rid)
-                        req.dropped = now
-                        self.n_timed_out += 1
-                else:
-                    break  # heap is deadline-ordered; the rest are feasible
+        with span("orloj.sched.drop"):
+            for bs, st in self._bs_state.items():
+                while st.deadline_heap:
+                    deadline, rid = st.deadline_heap[0]
+                    req = self._pending.get(rid)
+                    if req is None or bs not in self._feasible.get(rid, ()):
+                        heapq.heappop(st.deadline_heap)  # lazy removal
+                        continue
+                    est = self.estimate_batch_latency(req, bs) * self.cfg.drop_safety
+                    if now + est > deadline:
+                        heapq.heappop(st.deadline_heap)
+                        st.hull.delete(rid)
+                        self._feasible[rid].discard(bs)
+                        if not self._feasible[rid]:  # line 13–14: timed out
+                            self._remove(rid)
+                            req.dropped = now
+                            self.n_timed_out += 1
+                    else:
+                        break  # heap is deadline-ordered; the rest are feasible
 
     def _remove(self, rid: int) -> None:
         for bs in sorted(self._feasible.pop(rid, set())):
@@ -432,25 +445,27 @@ class OrlojScheduler:
     def _pop(self, candidate: int, now: float) -> Batch | None:
         """PopBatch: top ``candidate`` requests by ORLOJ score, in one
         fixed-x top-k pop (avoids k cascading tombstone purges)."""
-        x = self._x(now)
-        st = self._bs_state[candidate]
-        picked: list[Request] = []
-        for rid, _val in st.hull.pop_topk(x, candidate):
-            req = self._pending[rid]
-            picked.append(req)
-            self._feasible[rid].discard(candidate)
-            self._remove(rid)
+        with span("orloj.sched.pop"):
+            x = self._x(now)
+            st = self._bs_state[candidate]
+            picked: list[Request] = []
+            for rid, _val in st.hull.pop_topk(x, candidate):
+                req = self._pending[rid]
+                picked.append(req)
+                self._feasible[rid].discard(candidate)
+                self._remove(rid)
         if not picked:
             return None
         return Batch(picked, candidate)
 
     def next_batch(self, now: float) -> tuple[Batch | None, float | None]:
         """One scheduler iteration.  Returns (batch, next_wake_time)."""
-        best = self._prepare(now)
-        if best is None:
-            wake = self._milestones[0][0] if self._milestones else None
-            return None, wake
-        batch = self._pop(best[1], now)
+        with span("orloj.sched.next_batch"):
+            best = self._prepare(now)
+            if best is None:
+                wake = self._milestones[0][0] if self._milestones else None
+                return None, wake
+            batch = self._pop(best[1], now)
         if batch is None:
             return None, None
         return batch, None
@@ -533,23 +548,24 @@ class MultiModelOrlojScheduler:
 
     # -- batch selection ------------------------------------------------
     def next_batch(self, now: float) -> tuple[Batch | None, float | None]:
-        best: tuple[float, int, int] | None = None
-        best_model: str | None = None
-        for i, (m, sched) in enumerate(self._inner.items()):
-            cand = sched._prepare(now)
-            if cand is None:
-                continue
-            # deadline, larger batch on ties, then model roster order —
-            # a total order, so the winner is deterministic
-            key = (cand[0], -cand[1], i)
-            if best is None or key < best:
-                best, best_model = key, m
-        if best_model is None:
-            wakes = [
-                s._milestones[0][0] for s in self._inner.values() if s._milestones
-            ]
-            return None, (min(wakes) if wakes else None)
-        batch = self._inner[best_model]._pop(-best[1], now)
+        with span("orloj.sched.next_batch"):
+            best: tuple[float, int, int] | None = None
+            best_model: str | None = None
+            for i, (m, sched) in enumerate(self._inner.items()):
+                cand = sched._prepare(now)
+                if cand is None:
+                    continue
+                # deadline, larger batch on ties, then model roster order —
+                # a total order, so the winner is deterministic
+                key = (cand[0], -cand[1], i)
+                if best is None or key < best:
+                    best, best_model = key, m
+            if best_model is None:
+                wakes = [
+                    s._milestones[0][0] for s in self._inner.values() if s._milestones
+                ]
+                return None, (min(wakes) if wakes else None)
+            batch = self._inner[best_model]._pop(-best[1], now)
         if batch is None:
             return None, None
         batch.model = best_model
@@ -563,3 +579,7 @@ class MultiModelOrlojScheduler:
     @property
     def n_timed_out(self) -> int:
         return sum(s.n_timed_out for s in self._inner.values())
+
+    @property
+    def n_scored(self) -> int:
+        return sum(s.n_scored for s in self._inner.values())

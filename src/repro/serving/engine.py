@@ -27,6 +27,7 @@ from ..core.eventloop import SimResult, Worker, run_event_loop, simulate
 from ..core.request import Request
 from ..core.scheduler import Batch
 from ..models import Model, ModelConfig
+from ..tracing import span
 from .batcher import bucket_for, make_padded_batch, padded_batch_size
 from .faults import FaultPlan
 from .trace import offered_rate
@@ -89,6 +90,15 @@ class JaxExecutor:
     def padded_batch_size(self, k: int) -> int:
         return padded_batch_size(k, self.cfg.batch_sizes)
 
+    def _pad_rows(self, tokens: np.ndarray) -> np.ndarray:
+        """Zero rows up to the next supported batch size."""
+        k = self.padded_batch_size(tokens.shape[0])
+        if k > tokens.shape[0]:
+            tokens = np.concatenate(
+                [tokens, np.zeros((k - tokens.shape[0],) + tokens.shape[1:], tokens.dtype)]
+            )
+        return tokens
+
     def _run(self, tokens: np.ndarray) -> tuple[float, int]:
         """Execute one padded batch; returns ``(measured_ms, padded_k)``.
 
@@ -96,26 +106,34 @@ class JaxExecutor:
         latency model must be fit against it (not the requested k), or the
         scheduler's Eq.-3 estimates diverge from measurements whenever a
         batch is padded up to the next supported size."""
-        k = self.padded_batch_size(tokens.shape[0])
-        if k > tokens.shape[0]:
-            tokens = np.concatenate(
-                [tokens, np.zeros((k - tokens.shape[0],) + tokens.shape[1:], tokens.dtype)]
-            )
+        with span("orloj.exec.pad"):
+            tokens = self._pad_rows(tokens)
+        return self._execute(tokens)
+
+    def _execute(self, tokens: np.ndarray) -> tuple[float, int]:
+        """Run a batch already padded to a supported size (see :meth:`_run`)."""
         key = tokens.shape
-        batch = {"tokens": jax.device_put(tokens, self.device)}
+        with span("orloj.exec.put"):
+            batch = {"tokens": jax.device_put(tokens, self.device)}
         if key not in self._compiled:
             # warm the cache so compile time never pollutes a measurement
-            jax.block_until_ready(self._fwd(self.params, batch))
+            with span("orloj.exec.compile"):
+                jax.block_until_ready(self._fwd(self.params, batch))
             self._compiled.add(key)
         t0 = time.perf_counter()  # simlint: ignore[R1] -- this executor's whole job is measuring real batch latency
-        jax.block_until_ready(self._fwd(self.params, batch))
-        return (time.perf_counter() - t0) * 1e3, k  # simlint: ignore[R1] -- real batch latency measurement
+        with span("orloj.exec.dispatch"):
+            out = self._fwd(self.params, batch)
+        with span("orloj.exec.wait"):
+            jax.block_until_ready(out)
+        return (time.perf_counter() - t0) * 1e3, key[0]  # simlint: ignore[R1] -- real batch latency measurement
 
     def __call__(self, batch: Batch, now: float) -> float:
         # Admission (make_requests) caps lengths at the largest bucket, so
         # overflow here is a programming error — fail loudly.
-        padded = make_padded_batch(batch.requests, self.cfg.buckets, overflow="error")
-        ms, k_pad = self._run(padded.tokens)
+        with span("orloj.exec.pad"):
+            padded = make_padded_batch(batch.requests, self.cfg.buckets, overflow="error")
+            tokens = self._pad_rows(padded.tokens)
+        ms, k_pad = self._execute(tokens)
         self.measured.append((k_pad, padded.labels_bucket, ms))
         return ms
 
@@ -239,20 +257,23 @@ class DecodeJaxExecutor:
         b, h, hd = self.max_batch, self.n_heads, self.head_dim
         # Synthetic values are drawn OUTSIDE the timed region: the
         # measurement prices the kernel step, not host-side rng.
-        q = jnp.asarray(self._rng.standard_normal((b, h, hd)), jnp.float32)
-        nk = jnp.asarray(
-            self._rng.standard_normal((b, self.n_kv, hd)), jnp.float32
-        )
-        nv = jnp.asarray(
-            self._rng.standard_normal((b, self.n_kv, hd)), jnp.float32
-        )
-        active = self._valid > 0  # slots currently holding a request
+        with span("orloj.decode.values"):
+            q = jnp.asarray(self._rng.standard_normal((b, h, hd)), jnp.float32)
+            nk = jnp.asarray(
+                self._rng.standard_normal((b, self.n_kv, hd)), jnp.float32
+            )
+            nv = jnp.asarray(
+                self._rng.standard_normal((b, self.n_kv, hd)), jnp.float32
+            )
+            active = self._valid > 0  # slots currently holding a request
         t0 = time.perf_counter()  # simlint: ignore[R1] -- real decode-step latency measurement
-        kc, vc, valid, out = self._step(
-            self._kc, self._vc, self._valid, active, q, nk, nv,
-            use_pallas=self.use_pallas, block_k=self.block_k,
-        )
-        jax.block_until_ready(out)
+        with span("orloj.decode.dispatch"):
+            kc, vc, valid, out = self._step(
+                self._kc, self._vc, self._valid, active, q, nk, nv,
+                use_pallas=self.use_pallas, block_k=self.block_k,
+            )
+        with span("orloj.decode.wait"):
+            jax.block_until_ready(out)
         ms = (time.perf_counter() - t0) * 1e3  # simlint: ignore[R1] -- real decode-step latency measurement
         self._kc, self._vc, self._valid = kc, vc, valid
         # (B, H, hd) attention output of the last step — synthetic-valued,
@@ -276,30 +297,32 @@ class DecodeJaxExecutor:
                 n_tok = min(l, bucket)
                 toks[i, :n_tok] = self._rng.integers(1, 1000, size=n_tok)
             ms, _ = self.prefill._run(toks)
-        for r, l in zip(joined, lens):
-            if not self._free:
-                raise RuntimeError(
-                    f"decode executor capacity exceeded: {len(self._slot)} "
-                    f"active slots of {self.max_batch}; the token scheduler "
-                    f"must admit at most max_batch concurrent requests"
-                )
-            slot = self._free.pop()
-            self._slot[r.rid] = slot
-            n_ctx = min(l, self.max_cache)
-            kv = self._rng.standard_normal(
-                (2, self.n_kv, n_ctx, self.head_dim)
-            ).astype(np.float32)
-            self._kc = self._kc.at[slot, :, :n_ctx, :].set(kv[0])
-            self._vc = self._vc.at[slot, :, :n_ctx, :].set(kv[1])
-            self._valid = self._valid.at[slot].set(n_ctx)
+        with span("orloj.decode.seed"):
+            for r, l in zip(joined, lens):
+                if not self._free:
+                    raise RuntimeError(
+                        f"decode executor capacity exceeded: {len(self._slot)} "
+                        f"active slots of {self.max_batch}; the token scheduler "
+                        f"must admit at most max_batch concurrent requests"
+                    )
+                slot = self._free.pop()
+                self._slot[r.rid] = slot
+                n_ctx = min(l, self.max_cache)
+                kv = self._rng.standard_normal(
+                    (2, self.n_kv, n_ctx, self.head_dim)
+                ).astype(np.float32)
+                self._kc = self._kc.at[slot, :, :n_ctx, :].set(kv[0])
+                self._vc = self._vc.at[slot, :, :n_ctx, :].set(kv[1])
+                self._valid = self._valid.at[slot].set(n_ctx)
         return ms
 
     def _release_departed(self, active: Sequence[Request]) -> None:
-        live = {r.rid for r in active}
-        for rid in [r for r in self._slot if r not in live]:
-            slot = self._slot.pop(rid)
-            self._valid = self._valid.at[slot].set(0)
-            self._free.append(slot)
+        with span("orloj.decode.release"):
+            live = {r.rid for r in active}
+            for rid in [r for r in self._slot if r not in live]:
+                slot = self._slot.pop(rid)
+                self._valid = self._valid.at[slot].set(0)
+                self._free.append(slot)
 
     # ------------------------------------------------------------- API
     def calibrate(self, reps: int = 3) -> float:
