@@ -16,6 +16,20 @@ import numpy as np
 
 Params = dict[str, Any]
 
+# Weight leaves (by key, in any model here) that the forward only ever uses
+# cast: as a matrix-product operand after ``.astype`` to the activations'
+# dtype, or cast to the compute dtype outright (the embedding gather, the
+# frontend projection).  A chip's default-precision product rounds its
+# operands to bfloat16, so holding these leaves in the compute dtype gives
+# the same logits there without a cast of every weight on every call
+# (``Model.serving_params``).  Norm scales, biases and SSM parameters
+# (``a_log``, ``dt_bias``, ``conv``, ...) are used in float32 arithmetic
+# and are not listed.
+COMPUTE_DTYPE_LEAVES = frozenset(
+    {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "table", "lm_head",
+     "frontend_proj", "router"}
+)
+
 
 def _init(rng, shape, scale=None, dtype=jnp.float32):
     scale = scale if scale is not None else 1.0 / np.sqrt(shape[0])

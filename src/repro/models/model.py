@@ -30,7 +30,14 @@ from .blocks import (
     init_block_cache,
 )
 from .config import ModelConfig
-from .layers import embed_apply, init_embedding, init_norm, norm_apply, _init
+from .layers import (
+    COMPUTE_DTYPE_LEAVES,
+    _init,
+    embed_apply,
+    init_embedding,
+    init_norm,
+    norm_apply,
+)
 
 Params = dict[str, Any]
 
@@ -153,6 +160,23 @@ class Model:
     def logits(self, params: Params, batch: dict[str, jax.Array]) -> jax.Array:
         h, _ = self.hidden_states(params, batch)
         return self._head(params, h)
+
+    def serving_params(self, params: Params) -> Params:
+        """``params`` with each leaf of ``COMPUTE_DTYPE_LEAVES`` held in the
+        compute dtype (``cfg.dtype``), and every other leaf as it is.
+
+        For a server, made once per set of weights: the forward then reads
+        those weights in the dtype it computes in instead of converting the
+        float32 masters on every call.  Leaves already in the compute dtype
+        pass through, so with a float32 ``cfg.dtype`` this changes nothing."""
+
+        def cast(path, leaf):
+            name = getattr(path[-1], "key", None)
+            if name in COMPUTE_DTYPE_LEAVES and leaf.dtype != self.dtype:
+                return leaf.astype(self.dtype)
+            return leaf
+
+        return jax.tree_util.tree_map_with_path(cast, params)
 
     # -------------------------------------------------------------- loss
     def loss(self, params: Params, batch: dict[str, jax.Array]) -> jax.Array:

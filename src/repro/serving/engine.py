@@ -62,7 +62,12 @@ class JaxExecutor:
 
     With ``device`` set, the executor holds its own copy of the params on
     that device and runs every batch there (one replica per chip); without
-    it, arrays go to JAX's default device."""
+    it, arrays go to JAX's default device.
+
+    The forward runs on a compute-dtype copy of the weights it only uses
+    rounded to that dtype (:meth:`Model.serving_params`), made once each
+    time :attr:`params` is assigned and never per batch; :attr:`params`
+    itself stays the (float32) master weights as assigned."""
 
     MEASURED_LOG_CAP = 4096
 
@@ -71,14 +76,37 @@ class JaxExecutor:
     ):
         self.model = model
         self.device = device
-        self.params = params if device is None else jax.device_put(params, device)
         self.cfg = cfg
         self._fwd = jax.jit(
             lambda p, batch: self.model.logits(p, batch),
         )
+        self._cast = jax.jit(model.serving_params)
+        self.n_weight_casts = 0  # served copies made: one per assignment
+        self.cast_bytes_saved = 0  # master bytes a forward no longer converts
+        self.params = params if device is None else jax.device_put(params, device)
         self._compiled: set[tuple[int, int]] = set()
         self.measured: deque[tuple[int, int, float]] = deque(
             maxlen=self.MEASURED_LOG_CAP
+        )
+
+    @property
+    def params(self):
+        """The weights as assigned; the forward runs on their served copy."""
+        return self._params
+
+    @params.setter
+    def params(self, params) -> None:
+        self._params = params
+        self._served = None  # the old copy goes before the new one is made
+        if params is None:
+            return
+        with span("orloj.exec.cast"):
+            self._served = jax.block_until_ready(self._cast(params))
+        self.n_weight_casts += 1
+        self.cast_bytes_saved = sum(
+            m.nbytes
+            for m, s in zip(jax.tree.leaves(params), jax.tree.leaves(self._served))
+            if m.dtype != s.dtype
         )
 
     def drain_measured(self) -> list[tuple[int, int, float]]:
@@ -118,11 +146,11 @@ class JaxExecutor:
         if key not in self._compiled:
             # warm the cache so compile time never pollutes a measurement
             with span("orloj.exec.compile"):
-                jax.block_until_ready(self._fwd(self.params, batch))
+                jax.block_until_ready(self._fwd(self._served, batch))
             self._compiled.add(key)
         t0 = time.perf_counter()  # simlint: ignore[R1] -- this executor's whole job is measuring real batch latency
         with span("orloj.exec.dispatch"):
-            out = self._fwd(self.params, batch)
+            out = self._fwd(self._served, batch)
         with span("orloj.exec.wait"):
             jax.block_until_ready(out)
         return (time.perf_counter() - t0) * 1e3, key[0]  # simlint: ignore[R1] -- real batch latency measurement

@@ -92,6 +92,8 @@ def test_executor_for_device_holds_its_own_params(engine):
     assert ex is not engine.executor
     assert engine.executor_for(device=dev) is ex
     assert all(x.devices() == {dev} for x in jax.tree.leaves(ex.params))
+    assert all(x.devices() == {dev} for x in jax.tree.leaves(ex._served))
+    assert ex.n_weight_casts == 1
     ms, k_pad = ex._run(np.ones((2, 16), np.int32))
     assert k_pad == 2 and ms > 0.0
     slow = engine.executor_for(2.0, device=dev)

@@ -13,6 +13,7 @@ this is the only test file that describes it.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.moe_gating import moe_gating_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.models import Model
+from repro.serving.engine import EngineConfig, JaxExecutor
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -132,3 +134,30 @@ def test_orloj_gpt_padded_forward_fits_one_chip(one_chip):
         + mem.generated_code_size_in_bytes
     )
     assert 0 < total < V5E_HBM_BYTES, total
+
+
+_PARAM_CONVERT = re.compile(r"= \S+ convert\(%p__(\w+?)__\.\d+\)")
+
+
+def _converted_leaves(text: str) -> set[str]:
+    """Keys of the weight leaves whose entry parameter the compiled program
+    converts (``p['blocks'][0]['mlp']['w_up']`` is ``p__blocks___0___mlp____w_up__``)."""
+    return {m.group(1).split("___")[-1].lstrip("_") for m in _PARAM_CONVERT.finditer(text)}
+
+
+def test_orloj_gpt_served_forward_converts_no_weight(one_chip):
+    """The executor's forward at full width: on the float32 masters the
+    compiled program rounds the stacked layer weights and the embedding
+    table to bfloat16 on every call; on the served copy it converts none."""
+    model = Model(get_config("orloj_gpt"))
+    ex = JaxExecutor(model, None, EngineConfig())
+    masters = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    served = jax.eval_shape(model.serving_params, masters)
+    batch = {"tokens": _spec((8, 256), jnp.int32, one_chip)}
+
+    def converted(params):
+        specs = jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip), params)
+        return _converted_leaves(ex._fwd.lower(specs, batch).compile().as_text())
+
+    assert {"wq", "wk", "wv", "wo", "w_up", "w_down", "table"} <= converted(masters)
+    assert converted(served) == set()

@@ -187,6 +187,35 @@ def test_every_batch_shows_each_executor_phase_once_in_order(served):
     assert n_exec == len(EXEC_PHASES) * len(batches) + len(seen)
 
 
+def test_weights_are_cast_once_per_assignment_and_never_in_a_batch(tmp_path):
+    eng = ServingEngine(TINY, EngineConfig(buckets=(8, 16), batch_sizes=(1, 2), profile_reps=1))
+    ex = eng.executor
+    lm = BatchLatencyModel(c0=1.0, c1=0.01)
+    reqs, hist = eng.make_requests(
+        20, lm, length_sampler=lambda rng: int(rng.integers(2, 17)),
+        slo_scale=200.0, utilization=0.6, seed=6,
+    )
+    dists = {a: EmpiricalDistribution.from_samples(x) for a, x in hist.items() if len(x) >= 2}
+    sched = OrlojScheduler(lm, cfg=SchedulerConfig(batch_sizes=(1, 2)), initial_dists=dists)
+    eng.executor = _BatchSpan(ex)
+    weights = ex.params
+
+    def run():
+        ex.params = None  # no copy to make
+        ex.params = weights
+        res = eng.serve(reqs, sched)
+        ex.params = weights
+        return res
+
+    res, spans = _traced(tmp_path, run)
+    casts = [sp for sp in spans if sp[0] == "orloj.exec.cast"]
+    batches = [sp for sp in spans if sp[0] == "test.batch"]
+    assert len(casts) == 2 and ex.n_weight_casts == 3  # the third made the executor
+    assert len(batches) == res.n_batches > 0
+    for c in casts:
+        assert not any(b[1] < c[2] and c[1] < b[2] for b in batches), c
+
+
 def test_scheduler_phase_spans_nest_inside_its_hook_spans(served):
     _, spans, _ = served
     hooks = [sp for sp in spans if sp[0] in SCHED_HOOKS]
