@@ -1,6 +1,6 @@
 """Architecture registry + assigned input shapes.
 
-Ten architectures assigned from the public pool (each config cites its
+Eleven architectures assigned from the public pool (each config cites its
 source), plus the paper-scale example model.  ``get_config(name)`` returns
 the full published configuration; ``get_config(name).reduced()`` the
 CPU-smoke variant.  ``for_shape`` applies shape-driven adaptations (e.g.
@@ -26,6 +26,7 @@ ARCHS = [
     "hymba_1_5b",
     "xlstm_1_3b",
     "granite_34b",
+    "granite_4_h_micro",
     "orloj_gpt",  # paper-scale example model (~100M)
 ]
 

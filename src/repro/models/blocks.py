@@ -1,5 +1,6 @@
 """Decoder blocks: standard attention+MLP/MoE, Hymba hybrid (parallel
-attention ∥ Mamba heads), and xLSTM (mLSTM / sLSTM cells)."""
+attention ∥ Mamba heads), xLSTM (mLSTM / sLSTM cells), and Mamba-2 + MLP
+(Granite 4.0-H, beside its attention blocks)."""
 
 from __future__ import annotations
 
@@ -26,6 +27,8 @@ Params = dict[str, Any]
 
 
 def block_kind(cfg: ModelConfig, layer_idx: int) -> str:
+    if cfg.layer_pattern:
+        return {"M": "mamba2", "A": "attn"}[cfg.layer_pattern[layer_idx % len(cfg.layer_pattern)]]
     if cfg.block_pattern == "xlstm":
         return (
             "slstm"
@@ -49,9 +52,14 @@ def init_block(rng, cfg: ModelConfig, layer_idx: int) -> Params:
     if kind == "slstm":
         p["cell"] = ssm.init_slstm(ks[1], d, cfg.n_heads)
         return p
-    p["attn"] = init_attention(
-        ks[1], d, cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    )
+    if kind == "mamba2":
+        p["mamba2"] = ssm.init_mamba2(
+            ks[1], d, cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state, cfg.mamba_conv
+        )
+    else:
+        p["attn"] = init_attention(
+            ks[1], d, cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+        )
     if kind == "hymba":
         p["mamba"] = ssm.init_mamba(ks[2], d, cfg.ssm_state)
         p["norm_attn"] = init_norm(ks[3], d, "rmsnorm")
@@ -67,6 +75,15 @@ def init_block(rng, cfg: ModelConfig, layer_idx: int) -> Params:
 
 
 # --------------------------------------------------------------- forward
+def _mamba2_sizes(cfg: ModelConfig) -> dict:
+    return dict(n_heads=cfg.mamba_heads, head_dim=cfg.mamba_head_dim, n_state=cfg.ssm_state)
+
+
+def _residual(cfg: ModelConfig, y: jax.Array) -> jax.Array:
+    """A residual branch's output, times ``residual_multiplier`` (Granite)."""
+    return y if cfg.residual_multiplier == 1.0 else y * cfg.residual_multiplier
+
+
 def _tp_only_constraints(params: Params) -> Params:
     """Constrain weight leaves to their tensor-parallel-only layout: GSPMD
     then materialises them via a weight all-gather over the FSDP axis
@@ -117,24 +134,31 @@ def block_apply(
     if kind == "slstm":
         return x + ssm.slstm_apply(params["cell"], h, cfg.n_heads), aux
 
-    attn_out = attention_apply(
-        params["attn"],
-        h,
-        n_kv=cfg.n_kv_heads,
-        rope_theta=cfg.rope_theta,
-        sliding_window=cfg.sliding_window,
-        softcap=cfg.logit_softcap,
-        repeat_kv=cfg.gqa_repeat_kv,
-    )
-    if kind == "hymba":
-        ssm_out = ssm.mamba_apply(params["mamba"], h, chunk)
-        mix = 0.5 * (
-            norm_apply(params["norm_attn"], attn_out, "rmsnorm")
-            + norm_apply(params["norm_ssm"], ssm_out, "rmsnorm")
-        )
-        x = x + mix
+    if kind == "mamba2":
+        x = x + _residual(cfg, ssm.mamba2_apply(
+            params["mamba2"], h, chunk=cfg.mamba_chunk, **_mamba2_sizes(cfg)
+        ))
     else:
-        x = x + attn_out
+        attn_out = attention_apply(
+            params["attn"],
+            h,
+            n_kv=cfg.n_kv_heads,
+            rope_theta=cfg.rope_theta,
+            sliding_window=cfg.sliding_window,
+            softcap=cfg.logit_softcap,
+            repeat_kv=cfg.gqa_repeat_kv,
+            rope=cfg.position_embedding == "rope",
+            scale=cfg.attention_multiplier,
+        )
+        if kind == "hymba":
+            ssm_out = ssm.mamba_apply(params["mamba"], h, chunk)
+            mix = 0.5 * (
+                norm_apply(params["norm_attn"], attn_out, "rmsnorm")
+                + norm_apply(params["norm_ssm"], ssm_out, "rmsnorm")
+            )
+            x = x + mix
+        else:
+            x = x + _residual(cfg, attn_out)
 
     h2 = norm_apply(params["norm2"], x, cfg.norm)
     if cfg.is_moe:
@@ -145,7 +169,7 @@ def block_apply(
             y = y + mlp_apply(params["mlp"], h2, cfg.mlp)
         x = x + y
     elif cfg.mlp != "none":
-        x = x + mlp_apply(params["mlp"], h2, cfg.mlp)
+        x = x + _residual(cfg, mlp_apply(params["mlp"], h2, cfg.mlp))
     return x, aux
 
 
@@ -159,6 +183,10 @@ def init_block_cache(
         return {"cell": ssm.init_mlstm_cache(batch, d, cfg.n_heads)}
     if kind == "slstm":
         return {"cell": ssm.init_slstm_cache(batch, d)}
+    if kind == "mamba2":
+        return {"mamba2": ssm.init_mamba2_cache(
+            batch, conv_w=cfg.mamba_conv, dtype=dtype, **_mamba2_sizes(cfg)
+        )}
     eff_len = min(cache_len, cfg.sliding_window) if cfg.sliding_window else cache_len
     c: Params = {
         "kv": init_kv_cache(
@@ -188,27 +216,34 @@ def block_decode(
         out, c2 = ssm.slstm_decode(params["cell"], h, cache["cell"], cfg.n_heads)
         return x + out, {"cell": c2}
 
-    attn_out, kv2 = attention_decode(
-        params["attn"],
-        h,
-        cache["kv"],
-        pos,
-        n_kv=cfg.n_kv_heads,
-        rope_theta=cfg.rope_theta,
-        sliding_window=cfg.sliding_window,
-        softcap=cfg.logit_softcap,
-    )
-    new_cache: Params = {"kv": kv2}
-    if kind == "hymba":
-        ssm_out, mc2 = ssm.mamba_decode(params["mamba"], h, cache["mamba"])
-        mix = 0.5 * (
-            norm_apply(params["norm_attn"], attn_out, "rmsnorm")
-            + norm_apply(params["norm_ssm"], ssm_out, "rmsnorm")
-        )
-        x = x + mix
-        new_cache["mamba"] = mc2
+    if kind == "mamba2":
+        out, c2 = ssm.mamba2_decode(params["mamba2"], h, cache["mamba2"], **_mamba2_sizes(cfg))
+        x = x + _residual(cfg, out)
+        new_cache: Params = {"mamba2": c2}
     else:
-        x = x + attn_out
+        attn_out, kv2 = attention_decode(
+            params["attn"],
+            h,
+            cache["kv"],
+            pos,
+            n_kv=cfg.n_kv_heads,
+            rope_theta=cfg.rope_theta,
+            sliding_window=cfg.sliding_window,
+            softcap=cfg.logit_softcap,
+            rope=cfg.position_embedding == "rope",
+            scale=cfg.attention_multiplier,
+        )
+        new_cache = {"kv": kv2}
+        if kind == "hymba":
+            ssm_out, mc2 = ssm.mamba_decode(params["mamba"], h, cache["mamba"])
+            mix = 0.5 * (
+                norm_apply(params["norm_attn"], attn_out, "rmsnorm")
+                + norm_apply(params["norm_ssm"], ssm_out, "rmsnorm")
+            )
+            x = x + mix
+            new_cache["mamba"] = mc2
+        else:
+            x = x + _residual(cfg, attn_out)
 
     h2 = norm_apply(params["norm2"], x, cfg.norm)
     if cfg.is_moe:
@@ -219,5 +254,5 @@ def block_decode(
             y = y + mlp_apply(params["mlp"], h2, cfg.mlp)
         x = x + y
     elif cfg.mlp != "none":
-        x = x + mlp_apply(params["mlp"], h2, cfg.mlp)
+        x = x + _residual(cfg, mlp_apply(params["mlp"], h2, cfg.mlp))
     return x, new_cache

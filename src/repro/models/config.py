@@ -20,6 +20,8 @@ class ModelConfig:
 
     # Attention
     rope_theta: float = 10_000.0
+    position_embedding: str = "rope"  # rope | nope (no positions at all)
+    attention_multiplier: float = 0.0  # score scale; 0 → 1/sqrt(head_dim)
     sliding_window: int = 0  # 0 → full attention; >0 → ring-buffer window
     causal: bool = True
 
@@ -27,6 +29,11 @@ class ModelConfig:
     norm: str = "rmsnorm"  # rmsnorm | layernorm | nonparam_ln
     mlp: str = "swiglu"  # swiglu | gelu | relu2 | none
     logit_softcap: float = 0.0
+    # Granite scalars: embedding × multiplier (0 → sqrt(d_model)), each
+    # residual branch × multiplier, logits ÷ scaling.
+    embedding_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # Mixture-of-experts
     n_experts: int = 0
@@ -39,6 +46,17 @@ class ModelConfig:
     block_pattern: str = "attn"  # attn | hymba | xlstm
     slstm_every: int = 8  # xLSTM: every n-th block is an sLSTM block
     mlstm_chunk: int = 256  # chunkwise-parallel mLSTM chunk length
+    # One period of layer kinds, repeated over the depth: "M" a Mamba-2
+    # mixer, "A" attention (Granite 4.0-H: "MMMMMAMMMM").  Empty: every
+    # layer is ``block_pattern``'s kind.
+    layer_pattern: str = ""
+    # Mamba-2 mixer (one group: B and C shared by all heads; state size
+    # ``ssm_state``): heads of ``mamba_head_dim``, a causal depthwise conv
+    # of width ``mamba_conv``, the SSD computed in chunks of ``mamba_chunk``.
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_conv: int = 4
+    mamba_chunk: int = 256
 
     # Modality frontend stub (§carve-out: embeddings provided externally)
     frontend: str = ""  # "" | vision | audio
@@ -80,6 +98,11 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def mamba_inner(self) -> int:
+        """Width of the Mamba-2 mixer's inner stream (heads × head dim)."""
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
     def uses_attention(self) -> bool:
         return self.block_pattern in ("attn", "hymba")
 
@@ -95,7 +118,13 @@ class ModelConfig:
         else:
             mlp = 2 * d * ff
         per_layer = 0
-        if self.block_pattern == "xlstm":
+        if self.layer_pattern:
+            di, n = self.mamba_inner, self.ssm_state
+            mamba = d * (2 * di + 2 * n + self.mamba_heads) + (self.mamba_conv + 1) * (di + 2 * n)
+            mamba += 3 * self.mamba_heads + di + di * d
+            kinds = [self.layer_pattern[i % len(self.layer_pattern)] for i in range(self.n_layers)]
+            per_layer = sum(mamba if k == "M" else attn for k in kinds) / self.n_layers + mlp
+        elif self.block_pattern == "xlstm":
             # mLSTM: qkv + gates + out; treated as ~4 d², no FFN
             per_layer = 5 * d * d
         else:
@@ -109,7 +138,7 @@ class ModelConfig:
             else:
                 per_layer += mlp
         embed = self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        return self.n_layers * per_layer + embed
+        return int(self.n_layers * per_layer) + embed
 
     @property
     def n_active_params_estimate(self) -> int:
@@ -123,7 +152,8 @@ class ModelConfig:
         return full - moe_all + moe_active
 
     def reduced(self, **overrides) -> "ModelConfig":
-        """Smoke-test variant: ≤2 layers, d_model ≤ 512, ≤4 experts."""
+        """Smoke-test variant: ≤2 layers, d_model ≤ 512, ≤4 experts, ≤4
+        Mamba-2 heads."""
         small = dict(
             n_layers=min(self.n_layers, 2),
             d_model=min(self.d_model, 256),
@@ -138,6 +168,11 @@ class ModelConfig:
             sliding_window=min(self.sliding_window, 64) if self.sliding_window else 0,
             mlstm_chunk=32,
             slstm_every=2,
+            # one layer of each kind the period holds ("MA" for Granite 4.0-H)
+            layer_pattern="".join(sorted(set(self.layer_pattern), reverse=True)),
+            mamba_heads=min(self.mamba_heads, 4),
+            ssm_state=min(self.ssm_state, 16),
+            mamba_chunk=16,
             scan_layers=False,
             remat=False,
             dtype="float32",

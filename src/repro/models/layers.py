@@ -27,7 +27,7 @@ Params = dict[str, Any]
 # and are not listed.
 COMPUTE_DTYPE_LEAVES = frozenset(
     {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "table", "lm_head",
-     "frontend_proj", "router"}
+     "frontend_proj", "router", "in_proj", "out_proj"}
 )
 
 
@@ -94,11 +94,13 @@ def init_attention(rng, d: int, n_heads: int, n_kv: int, head_dim: int) -> Param
     }
 
 
-def _gqa_scores(q: jax.Array, k: jax.Array, n_kv: int) -> jax.Array:
-    """q: (B,S,H,hd), k: (B,T,KV,hd) → scores (B, KV, q_per_kv, S, T)."""
+def _gqa_scores(q: jax.Array, k: jax.Array, n_kv: int, scale: float = 0.0) -> jax.Array:
+    """q: (B,S,H,hd), k: (B,T,KV,hd) → scores (B, KV, q_per_kv, S, T),
+    times ``scale`` (0 → divided by sqrt(hd))."""
     b, s, h, hd = q.shape
     qg = q.reshape(b, s, n_kv, h // n_kv, hd)
-    return jnp.einsum("bskgh,btkh->bkgst", qg, k) / np.sqrt(hd).astype(np.float32)
+    raw = jnp.einsum("bskgh,btkh->bkgst", qg, k)
+    return raw * scale if scale else raw / np.sqrt(hd).astype(np.float32)
 
 
 def attention_apply(
@@ -111,8 +113,12 @@ def attention_apply(
     positions: jax.Array | None = None,
     softcap: float = 0.0,
     repeat_kv: bool = False,
+    rope: bool = True,
+    scale: float = 0.0,
 ) -> jax.Array:
     """Full (training / prefill) causal GQA attention.  x: (B, S, d).
+    ``rope=False``: no positions at all (NoPE); ``scale``: the scores'
+    multiplier (0 → 1/sqrt(head_dim)).
 
     ``repeat_kv=True`` broadcasts K/V to the full head count before the
     score einsums: all attention tensors are then (B, S, H, ·) and shard
@@ -123,11 +129,12 @@ def attention_apply(
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, params["wv"].astype(dtype))
-    if positions is None:
-        positions = jnp.arange(s)[None, :]
-    sin, cos = rope_tables(positions, q.shape[-1], rope_theta)
-    q = rope_apply(q, sin, cos)
-    k = rope_apply(k, sin, cos)
+    if rope:
+        if positions is None:
+            positions = jnp.arange(s)[None, :]
+        sin, cos = rope_tables(positions, q.shape[-1], rope_theta)
+        q = rope_apply(q, sin, cos)
+        k = rope_apply(k, sin, cos)
     h = q.shape[2]
 
     i = jnp.arange(s)[:, None]
@@ -141,7 +148,7 @@ def attention_apply(
         k = jnp.repeat(k, rep, axis=2)  # (B, S, H, hd)
         v = jnp.repeat(v, rep, axis=2)
         scores = jnp.einsum("bshk,bthk->bhst", q, k).astype(jnp.float32)
-        scores = scores / np.sqrt(q.shape[-1]).astype(np.float32)
+        scores = scores * scale if scale else scores / np.sqrt(q.shape[-1]).astype(np.float32)
         if softcap > 0:
             scores = jnp.tanh(scores / softcap) * softcap
         scores = jnp.where(mask[None, None], scores, -1e30)
@@ -149,7 +156,7 @@ def attention_apply(
         ctx = jnp.einsum("bhst,bthk->bshk", probs, v)
         return jnp.einsum("bshk,hkd->bsd", ctx, params["wo"].astype(dtype))
 
-    scores = _gqa_scores(q, k, n_kv).astype(jnp.float32)
+    scores = _gqa_scores(q, k, n_kv, scale).astype(jnp.float32)
     if softcap > 0:
         scores = jnp.tanh(scores / softcap) * softcap
     scores = jnp.where(mask[None, None, None], scores, -1e30)
@@ -177,8 +184,11 @@ def attention_decode(
     rope_theta: float,
     sliding_window: int = 0,
     softcap: float = 0.0,
+    rope: bool = True,
+    scale: float = 0.0,
 ) -> tuple[jax.Array, Params]:
-    """One-token decode with a KV cache.  x: (B, 1, d); ``pos`` scalar int.
+    """One-token decode with a KV cache.  x: (B, 1, d); ``pos`` scalar int;
+    ``rope`` and ``scale`` as in :func:`attention_apply`.
 
     With ``sliding_window > 0`` the cache is a ring buffer of length W
     (positions are absolute for RoPE; the slot is ``pos mod W``) — this is
@@ -190,16 +200,17 @@ def attention_decode(
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dtype))
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, params["wv"].astype(dtype))
-    posv = jnp.full((b, 1), pos)
-    sin, cos = rope_tables(posv, q.shape[-1], rope_theta)
-    q = rope_apply(q, sin, cos)
-    k = rope_apply(k, sin, cos)
+    if rope:
+        posv = jnp.full((b, 1), pos)
+        sin, cos = rope_tables(posv, q.shape[-1], rope_theta)
+        q = rope_apply(q, sin, cos)
+        k = rope_apply(k, sin, cos)
 
     slot = jnp.where(sliding_window > 0, pos % cache_len, pos)
     new_k = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
     new_v = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
 
-    scores = _gqa_scores(q, new_k.astype(dtype), n_kv).astype(jnp.float32)
+    scores = _gqa_scores(q, new_k.astype(dtype), n_kv, scale).astype(jnp.float32)
     if softcap > 0:
         scores = jnp.tanh(scores / softcap) * softcap
     idx = jnp.arange(cache_len)

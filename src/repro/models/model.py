@@ -52,51 +52,61 @@ class Model:
     def __init__(self, cfg: ModelConfig):
         import dataclasses
 
-        # xLSTM stacks are heterogeneous (mLSTM/sLSTM mix) but periodic:
-        # scan over homogeneous *groups* of `slstm_every` blocks when the
-        # depth divides evenly; otherwise fall back to unrolling.
+        # xLSTM (mLSTM/sLSTM mix) and patterned stacks (Granite 4.0-H's
+        # Mamba-2/attention period) are heterogeneous but periodic: scan
+        # over homogeneous *groups* of one period when the depth divides
+        # evenly; otherwise fall back to unrolling.
         self.unit = 1
-        if cfg.block_pattern == "xlstm":
-            if cfg.scan_layers and cfg.n_layers % cfg.slstm_every == 0:
-                self.unit = cfg.slstm_every
+        period = len(cfg.layer_pattern) or (cfg.slstm_every if cfg.block_pattern == "xlstm" else 1)
+        if period > 1:
+            if cfg.scan_layers and cfg.n_layers % period == 0:
+                self.unit = period
             else:
                 cfg = dataclasses.replace(cfg, scan_layers=False)
         self.cfg = cfg
         self.dtype = jnp.dtype(cfg.dtype)
+        self.param_dtype = jnp.dtype(cfg.param_dtype)
 
     @property
     def n_units(self) -> int:
         return self.cfg.n_layers // self.unit
 
     # ------------------------------------------------------------ init
+    def _stored(self, tree):
+        """``tree`` in ``cfg.param_dtype``: cast one block at a time, so the
+        float32 draws of a whole model never live at once."""
+        if self.param_dtype == jnp.float32:
+            return tree
+        return jax.tree.map(lambda x: x.astype(self.param_dtype), tree)
+
     def init(self, rng: jax.Array) -> Params:
         cfg = self.cfg
         k_embed, k_blocks, k_final, k_head, k_front = jax.random.split(rng, 5)
         params: Params = {}
         if cfg.frontend != "audio":
-            params["embed"] = init_embedding(k_embed, cfg.vocab_size, cfg.d_model)
+            params["embed"] = self._stored(init_embedding(k_embed, cfg.vocab_size, cfg.d_model))
         if cfg.frontend:
-            params["frontend_proj"] = _init(
+            params["frontend_proj"] = self._stored(_init(
                 k_front, (self.frontend_dim, cfg.d_model)
-            )
+            ))
         if cfg.scan_layers:
             unit = self.unit
             rngs = jax.random.split(k_blocks, self.n_units)
             params["blocks"] = jax.vmap(
                 lambda r: [
-                    init_block(jax.random.fold_in(r, i), cfg, i) for i in range(unit)
+                    self._stored(init_block(jax.random.fold_in(r, i), cfg, i)) for i in range(unit)
                 ]
             )(rngs)
         else:
             params["blocks"] = [
-                init_block(jax.random.fold_in(k_blocks, i), cfg, i)
+                self._stored(init_block(jax.random.fold_in(k_blocks, i), cfg, i))
                 for i in range(cfg.n_layers)
             ]
-        params["final_norm"] = init_norm(k_final, cfg.d_model, cfg.norm)
+        params["final_norm"] = self._stored(init_norm(k_final, cfg.d_model, cfg.norm))
         if not cfg.tie_embeddings:
-            params["lm_head"] = _init(
+            params["lm_head"] = self._stored(_init(
                 k_head, (cfg.d_model, cfg.vocab_size), scale=1.0 / np.sqrt(cfg.d_model)
-            )
+            ))
         return params
 
     @property
@@ -111,11 +121,17 @@ class Model:
             emb = batch["frontend_embeds"].astype(self.dtype)
             parts.append(emb @ params["frontend_proj"].astype(self.dtype))
         if "tokens" in batch and cfg.frontend != "audio":
-            parts.append(
-                embed_apply(params["embed"], batch["tokens"], self.dtype)
-                * np.sqrt(cfg.d_model).astype(np.float32)
-            )
+            parts.append(self._scaled(embed_apply(params["embed"], batch["tokens"], self.dtype)))
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+    def _scaled(self, emb: jax.Array) -> jax.Array:
+        """Token embeddings times ``embedding_multiplier``, kept in the
+        compute dtype; without one, times the NumPy float32 sqrt(d_model),
+        which promotes bf16 embeddings, and so every activation after
+        them, to float32."""
+        if self.cfg.embedding_multiplier:
+            return emb * self.cfg.embedding_multiplier
+        return emb * np.sqrt(self.cfg.d_model).astype(np.float32)
 
     # ----------------------------------------------------------- forward
     def hidden_states(self, params: Params, batch: dict[str, jax.Array]):
@@ -135,7 +151,12 @@ class Model:
 
             if cfg.remat:
                 body = jax.checkpoint(body, policy=_remat_policy(cfg))
-            (x, aux), _ = jax.lax.scan(body, (x, aux), params["blocks"])
+            # A patterned stack's period carries ~130 stacked weights through
+            # the loop, and a device trace names a while loop by its whole
+            # instruction text (~5 KB here): unroll it, so every op of the
+            # forward has a short name of its own.
+            unroll = {"unroll": self.n_units} if cfg.layer_pattern else {}
+            (x, aux), _ = jax.lax.scan(body, (x, aux), params["blocks"], **unroll)
         else:
             for i, bp in enumerate(params["blocks"]):
                 if cfg.remat:
@@ -154,8 +175,10 @@ class Model:
         cfg = self.cfg
         if cfg.tie_embeddings:
             table = params["embed"]["table"].astype(h.dtype)
-            return jnp.einsum("...d,vd->...v", h, table)
-        return jnp.einsum("...d,dv->...v", h, params["lm_head"].astype(h.dtype))
+            out = jnp.einsum("...d,vd->...v", h, table)
+        else:
+            out = jnp.einsum("...d,dv->...v", h, params["lm_head"].astype(h.dtype))
+        return out / cfg.logits_scaling if cfg.logits_scaling != 1.0 else out
 
     def logits(self, params: Params, batch: dict[str, jax.Array]) -> jax.Array:
         h, _ = self.hidden_states(params, batch)
@@ -262,9 +285,7 @@ class Model:
         if cfg.frontend == "audio":
             x = tokens.astype(self.dtype) @ params["frontend_proj"].astype(self.dtype)
         else:
-            x = embed_apply(params["embed"], tokens, self.dtype) * np.sqrt(
-                cfg.d_model
-            ).astype(np.float32)
+            x = self._scaled(embed_apply(params["embed"], tokens, self.dtype))
         if cfg.scan_layers:
             unit = self.unit
 

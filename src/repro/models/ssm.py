@@ -1,5 +1,6 @@
 """State-space and recurrent cells: Mamba (Hymba's parallel-SSM head),
-mLSTM and sLSTM (xLSTM blocks).
+Mamba-2 (Granite 4.0-H's mixer, chunked SSD), mLSTM and sLSTM (xLSTM
+blocks).
 
 TPU adaptation notes (see DESIGN.md): the CUDA selective-scan kernel of
 Mamba and the fused mLSTM kernels are re-expressed as *chunkwise-parallel*
@@ -153,6 +154,166 @@ def mamba_decode(
     out = (y @ params["out"].astype(dtype))[:, None]
     new_cache = {"h": h.astype(cache["h"].dtype), "conv": hist[:, 1:].astype(cache["conv"].dtype)}
     return out, new_cache
+
+
+# =====================================================================
+# Mamba-2 (SSD) — Granite 4.0-H's mixer
+# =====================================================================
+def init_mamba2(rng, d: int, n_heads: int, head_dim: int, n_state: int, conv_w: int = 4) -> Params:
+    """One group (B and C shared by every head).  ``in_proj`` gives, in this
+    order, the gate z (di), the conv stream xBC (di + 2N) and dt (heads);
+    dt's bias is softplus⁻¹ of a log-uniform draw in [1e-3, 1e-1] and
+    A = -(1..heads), as the published Mamba-2 initialises them."""
+    di = n_heads * head_dim
+    ks = jax.random.split(rng, 4)
+    dt = jnp.exp(jax.random.uniform(ks[2], (n_heads,), jnp.float32, np.log(1e-3), np.log(1e-1)))
+    return {
+        "in_proj": _init(ks[0], (d, 2 * di + 2 * n_state + n_heads)),
+        "conv_w": _init(ks[1], (conv_w, di + 2 * n_state), scale=1.0 / np.sqrt(conv_w)),
+        "conv_b": jnp.zeros((di + 2 * n_state,), jnp.float32),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "a_log": jnp.log(jnp.arange(1, n_heads + 1, dtype=jnp.float32)),
+        "d_skip": jnp.ones((n_heads,), jnp.float32),
+        "norm_scale": jnp.ones((di,), jnp.float32),
+        "out_proj": _init(ks[3], (di, d)),
+    }
+
+
+def _causal_conv(x: jax.Array, w: jax.Array, bias: jax.Array) -> jax.Array:
+    """Depthwise causal conv of width ``w.shape[0]`` over the sequence, then
+    SiLU; summed in float32, returned in ``x``'s dtype.  x: (B, S, C)."""
+    k, s = w.shape[0], x.shape[1]
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
+    y = sum(xp[:, i : i + s] * w[i].astype(jnp.float32) for i in range(k))
+    return jax.nn.silu(y + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def _gated_rmsnorm(y: jax.Array, z: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the whole inner width of y · silu(z), in float32."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return (g * scale.astype(jnp.float32)).astype(z.dtype)
+
+
+def ssd(
+    x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array, cm: jax.Array, chunk: int
+) -> jax.Array:
+    """The Mamba-2 scan h_t = exp(dt_t a) h_{t-1} + dt_t x_t ⊗ B_t,
+    y_t = h_t · C_t, in its chunked (state-space duality) form.
+
+    x: (B, S, H, P) in the compute dtype; dt: (B, S, H) float32; a: (H,)
+    float32 (negative); bm, cm: (B, S, N).  Returns y (B, S, H, P) float32.
+    Chunks hold ``min(chunk, S)`` positions (S is padded at the right to a
+    whole number of them; padding never reaches an earlier position).
+    Within a chunk y is a masked product C Bᵀ ∘ decay with dt·x; each chunk's
+    final state is passed to the next by a scan over chunks.  The decays
+    (dt·a, their cumulative sums, exp) and the passed states are float32;
+    the products' operands are in x's dtype."""
+    f32, dtype = jnp.float32, x.dtype
+    b, s, h, p = x.shape
+    n = bm.shape[-1]
+    q = min(chunk, s)
+    pad = -s % q
+    if pad:
+        x, dt, bm, cm = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)) for t in (x, dt, bm, cm)
+        )
+    nc = (s + pad) // q
+    x = x.reshape(b, nc, q, h, p)
+    dt = dt.reshape(b, nc, q, h)
+    bm = bm.reshape(b, nc, q, n)
+    cm = cm.reshape(b, nc, q, n)
+
+    cum = jnp.cumsum(dt * a, axis=2).transpose(0, 1, 3, 2)  # (b, c, h, q), ≤ 0, falling
+    causal = jnp.tril(jnp.ones((q, q), bool))
+    seg = jnp.where(causal, cum[..., :, None] - cum[..., None, :], -jnp.inf)
+    xdt = (x.astype(f32) * dt[..., None]).astype(dtype)
+    g = jnp.einsum("bcin,bcjn->bcij", cm, bm)
+    m = (g[:, :, None].astype(f32) * jnp.exp(seg)).astype(dtype)  # (b, c, h, i, j)
+    y = jnp.einsum("bchij,bcjhp->bcihp", m, xdt, preferred_element_type=f32)
+
+    to_end = jnp.exp(cum[..., -1:] - cum).transpose(0, 1, 3, 2)  # (b, c, q, h)
+    xw = (x.astype(f32) * (dt * to_end)[..., None]).astype(dtype)
+    states = jnp.einsum("bcjhp,bcjn->bchpn", xw, bm, preferred_element_type=f32)
+
+    def carry(h_in, inp):
+        st, decay = inp
+        return h_in * decay[..., None, None] + st, h_in
+
+    _, entering = jax.lax.scan(
+        carry,
+        jnp.zeros((b, h, p, n), f32),
+        (states.swapaxes(0, 1), jnp.exp(cum[..., -1]).swapaxes(0, 1)),
+    )
+    y_off = jnp.einsum(
+        "bcin,cbhpn->bcihp", cm, entering.astype(dtype), preferred_element_type=f32
+    ) * jnp.exp(cum).transpose(0, 1, 3, 2)[..., None]
+    return (y + y_off).reshape(b, nc * q, h, p)[:, :s]
+
+
+def mamba2_apply(
+    params: Params, x: jax.Array, *, n_heads: int, head_dim: int, n_state: int, chunk: int,
+    eps: float = 1e-5,
+) -> jax.Array:
+    """Mamba-2 mixer, x: (B, S, d) → (B, S, d): in_proj → (z, xBC, dt);
+    causal conv + SiLU over xBC; dt = softplus(dt + dt_bias), A = -exp(a_log);
+    y = SSD(x, dt, A, B, C) + D·x; gated RMSNorm with z; out_proj."""
+    b, s, _ = x.shape
+    dtype = x.dtype
+    di = n_heads * head_dim
+    with jax.named_scope("orloj.mamba2.in"):
+        zxbcdt = x @ params["in_proj"].astype(dtype)
+        z, xbc, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * n_state], axis=-1)
+    with jax.named_scope("orloj.mamba2.conv"):
+        xs, bm, cm = jnp.split(
+            _causal_conv(xbc, params["conv_w"], params["conv_b"]), [di, di + n_state], axis=-1
+        )
+        xs = xs.reshape(b, s, n_heads, head_dim)
+    with jax.named_scope("orloj.mamba2.ssd"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(params["a_log"].astype(jnp.float32))
+        y = ssd(xs, dt, a, bm, cm, chunk)
+        y = y + params["d_skip"].astype(jnp.float32)[:, None] * xs.astype(jnp.float32)
+    with jax.named_scope("orloj.mamba2.norm"):
+        y = _gated_rmsnorm(y.reshape(b, s, di), z, params["norm_scale"], eps)
+    with jax.named_scope("orloj.mamba2.out"):
+        return y @ params["out_proj"].astype(dtype)
+
+
+def init_mamba2_cache(
+    batch: int, n_heads: int, head_dim: int, n_state: int, conv_w: int = 4, dtype=jnp.float32
+) -> Params:
+    """The conv window's last ``conv_w - 1`` inputs and the float32 state."""
+    return {
+        "conv": jnp.zeros((batch, conv_w - 1, n_heads * head_dim + 2 * n_state), dtype),
+        "ssm": jnp.zeros((batch, n_heads, head_dim, n_state), jnp.float32),
+    }
+
+
+def mamba2_decode(
+    params: Params, x: jax.Array, cache: Params, *, n_heads: int, head_dim: int, n_state: int,
+    eps: float = 1e-5,
+) -> tuple[jax.Array, Params]:
+    """One-step decode of :func:`mamba2_apply` (the recurrence itself).
+    x: (B, 1, d)."""
+    f32, dtype = jnp.float32, x.dtype
+    b = x.shape[0]
+    di = n_heads * head_dim
+    zxbcdt = x[:, 0] @ params["in_proj"].astype(dtype)
+    z, xbc, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * n_state], axis=-1)
+    win = jnp.concatenate([cache["conv"].astype(f32), xbc[:, None].astype(f32)], axis=1)
+    conv = jnp.einsum("bkc,kc->bc", win, params["conv_w"].astype(f32))
+    conv = conv + params["conv_b"].astype(f32)
+    xs, bm, cm = jnp.split(jax.nn.silu(conv).astype(dtype).astype(f32), [di, di + n_state], axis=-1)
+    xs = xs.reshape(b, n_heads, head_dim)
+    dt = jax.nn.softplus(dt.astype(f32) + params["dt_bias"].astype(f32))  # (B, H)
+    decay = jnp.exp(dt * -jnp.exp(params["a_log"].astype(f32)))
+    st = cache["ssm"] * decay[..., None, None]
+    st = st + (dt[..., None] * xs)[..., None] * bm[:, None, None, :]
+    y = jnp.einsum("bhpn,bn->bhp", st, cm) + params["d_skip"].astype(f32)[:, None] * xs
+    y = _gated_rmsnorm(y.reshape(b, di), z, params["norm_scale"], eps)
+    out = (y @ params["out_proj"].astype(dtype))[:, None]
+    return out, {"conv": win[:, 1:].astype(cache["conv"].dtype), "ssm": st}
 
 
 # =====================================================================

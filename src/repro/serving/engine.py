@@ -67,7 +67,8 @@ class JaxExecutor:
     The forward runs on a compute-dtype copy of the weights it only uses
     rounded to that dtype (:meth:`Model.serving_params`), made once each
     time :attr:`params` is assigned and never per batch; :attr:`params`
-    itself stays the (float32) master weights as assigned."""
+    itself stays the master weights as assigned.  Leaves the copy does not
+    cast are shared with the masters."""
 
     MEASURED_LOG_CAP = 4096
 
@@ -80,7 +81,7 @@ class JaxExecutor:
         self._fwd = jax.jit(
             lambda p, batch: self.model.logits(p, batch),
         )
-        self._cast = jax.jit(model.serving_params)
+        self._cast = jax.jit(lambda xs: [x.astype(model.dtype) for x in xs])
         self.n_weight_casts = 0  # served copies made: one per assignment
         self.cast_bytes_saved = 0  # master bytes a forward no longer converts
         self.params = params if device is None else jax.device_put(params, device)
@@ -101,13 +102,24 @@ class JaxExecutor:
         if params is None:
             return
         with span("orloj.exec.cast"):
-            self._served = jax.block_until_ready(self._cast(params))
+            self._served = jax.block_until_ready(self._served_copy(params))
         self.n_weight_casts += 1
         self.cast_bytes_saved = sum(
             m.nbytes
             for m, s in zip(jax.tree.leaves(params), jax.tree.leaves(self._served))
             if m.dtype != s.dtype
         )
+
+    def _served_copy(self, params):
+        """:meth:`Model.serving_params` of ``params``: the leaves it casts,
+        cast in one program; every other leaf is the masters' own array,
+        so masters already in the compute dtype cost no second copy."""
+        leaves, tree = jax.tree.flatten(params)
+        want = jax.tree.leaves(jax.eval_shape(self.model.serving_params, params))
+        todo = [i for i, (m, w) in enumerate(zip(leaves, want)) if m.dtype != w.dtype]
+        for i, x in zip(todo, self._cast([leaves[i] for i in todo])):
+            leaves[i] = x
+        return jax.tree.unflatten(tree, leaves)
 
     def drain_measured(self) -> list[tuple[int, int, float]]:
         """Return the ``(padded_k, bucket, measured_ms)`` log and reset it."""

@@ -1,5 +1,6 @@
-"""Compile the serving path's kernels and the full-width ``orloj_gpt``
-forward for one described TPU v5e chip.
+"""Compile the serving path's kernels, the full-width ``orloj_gpt``
+forward and the full-width Granite-4.0-H-Micro forward for one described
+TPU v5e chip.
 
 No chip is needed: the TPU compiler is installed and compiles for a
 topology that is described, not attached.  It refuses what interpret mode
@@ -161,3 +162,22 @@ def test_orloj_gpt_served_forward_converts_no_weight(one_chip):
 
     assert {"wq", "wk", "wv", "wo", "w_up", "w_down", "table"} <= converted(masters)
     assert converted(served) == set()
+
+
+def test_granite_hybrid_served_forward_fits_one_chip(one_chip):
+    """Granite-4.0-H-Micro as the chip deployment holds it (every published
+    width, bfloat16 masters, which the executor serves as they are) at the
+    largest served shape, 4 x 1024: weights, output and temporaries fit."""
+    import dataclasses
+
+    model = Model(dataclasses.replace(get_config("granite_4_h_micro"), param_dtype="bfloat16"))
+    ex = JaxExecutor(model, None, EngineConfig())
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip), params)
+    compiled = ex._fwd.lower(params, {"tokens": _spec((4, 1024), jnp.int32, one_chip)}).compile()
+    mem = compiled.memory_analysis()
+    # the bf16 weights and the tokens, give or take the tiling of small leaves
+    assert abs(mem.argument_size_in_bytes - (2 * 3_191_396_096 + 4 * 4 * 1024)) < 2**20
+    assert mem.output_size_in_bytes == 2 * 4 * 1024 * 100_352  # bf16 logits
+    total = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    assert total < V5E_HBM_BYTES, total
