@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from typing import Sequence
 
 import numpy as np
 
@@ -39,6 +40,7 @@ from .request import PiecewiseStepCost, Request
 __all__ = [
     "BinScoreModel",
     "Score",
+    "StackedScoreModel",
     "DEFAULT_B",
     "RESET_EXPONENT",
     "aggregate_steps",
@@ -209,6 +211,82 @@ class BinScoreModel:
                         -self.b * d_rel
                     ) * math.exp(self.b * t_rel)
         return float(total)
+
+
+class StackedScoreModel:
+    """:meth:`BinScoreModel.score_many` at every batch size in one pass.
+
+    Built at a snapshot swap from the per-batch-size models (one shared
+    ``b``); row ``k`` of each result equals ``models[k].score_many`` bit
+    for bit.  The bins of one histogram are contiguous (``l2[j] ==
+    l1[j+1]``), so a single count ``c = #{edges < s}`` fixes both regime
+    indices, ``iA = max(c − 1, 0)`` and ``iB = min(c, bins)``.  The
+    prefix-sum differences of each regime ``c`` are precomputed with the
+    operations and order of ``score_many`` (``α = kc·e^{−b(D−base)}·A[c]``,
+    ``β = kc·B[c]``), and the next milestone over ``l2[:iA] ∪ l1[:iB]`` is
+    the next future ``D − edges[j]`` for ``j < c``.  Histograms with fewer
+    bins are padded with ``+inf`` edges, which no finite slack counts, so a
+    padded entry is never read.  Deadlines and ``t`` are finite.
+    """
+
+    def __init__(self, models: Sequence[BinScoreModel]):
+        if len({m.b for m in models}) != 1:
+            raise ValueError("stacked score models must share b")
+        self.b = models[0].b
+        self.models = tuple(models)
+        n_bs = len(models)
+        width = max(len(m.l1) for m in models) + 2  # regimes c = 0 … bins+1
+        edges = np.full((n_bs, 1, width - 1), np.inf)
+        alpha_tab = np.zeros((n_bs, width))
+        beta_tab = np.zeros((n_bs, width))
+        prev_edge = np.full((n_bs, width), -np.inf)  # [c] = edges[c−1]
+        for k, m in enumerate(models):
+            if not np.array_equal(m.l1[1:], m.l2[:-1]):
+                raise ValueError("histogram bins must be contiguous")
+            bins = len(m.l1)
+            e = np.concatenate([m.l1, m.l2[-1:]])
+            c = np.arange(bins + 2)
+            i_a = np.maximum(c - 1, 0)
+            i_b = np.minimum(c, bins)
+            edges[k, 0, : bins + 1] = e
+            alpha_tab[k, : bins + 2] = m._p_gap[i_a] - (
+                m._p_el1[i_b] - m._p_el1[i_a]
+            )
+            beta_tab[k, : bins + 2] = m._p_h[i_b] - m._p_h[i_a]
+            prev_edge[k, 1 : bins + 2] = e
+        self._edges = edges
+        self._alpha_tab = alpha_tab.ravel()
+        self._beta_tab = beta_tab.ravel()
+        self._prev_edge = prev_edge.ravel()
+        self._row0 = (np.arange(n_bs) * width)[:, None]
+        self._k = np.array([[m._k] for m in models])
+
+    def score_many(
+        self,
+        deadlines: np.ndarray,
+        costs: np.ndarray,
+        t: float,
+        base: float,
+    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(alpha, beta, milestone)``, each (n_bs, N): row ``k`` is
+        ``models[k].score_many(deadlines, costs, t, base)``."""
+        d = np.asarray(deadlines, dtype=np.float64)
+        c = np.asarray(costs, dtype=np.float64)
+        s = d - t
+        idx = (self._edges < s[:, None]).sum(axis=-1) + self._row0
+        ebD = np.exp(-self.b * (d - base))
+        kc = self._k * c
+        alpha = kc * ebD * self._alpha_tab[idx]
+        beta = kc * self._beta_tab[idx]
+        # BinScoreModel._next_future over the merged edges, the whole
+        # stack at once: step left past ulp-coincident candidates (<= t)
+        milestone = d - self._prev_edge[idx]
+        stale = milestone <= t
+        while stale.any():
+            idx = idx - stale
+            milestone = d - self._prev_edge[idx]
+            stale = milestone <= t
+        return alpha, beta, milestone
 
 
 def aggregate_steps(

@@ -12,10 +12,11 @@ Structure per Algorithm 1:
 - base-time reset for exponential-overflow handling (lines 2–4, §4.4).
 
 Hot path (DESIGN.md §Hot-path): arrivals are delivered in bulk through
-:meth:`OrlojScheduler.on_arrivals` — one :meth:`BinScoreModel.score_many`
-pass plus one :meth:`HullQueue.insert_many` block per batch size — and the
-full-recompute paths (base reset, profiler snapshot swap) rebuild each hull
-with :meth:`HullQueue.bulk_load` from a single vectorized scoring pass.
+:meth:`OrlojScheduler.on_arrivals` — one :meth:`StackedScoreModel.score_many`
+pass over every batch size plus one :meth:`HullQueue.insert_many` block per
+batch size — and the full-recompute paths (base reset, profiler snapshot
+swap) rebuild each hull with :meth:`HullQueue.bulk_load` from the same
+single scoring pass.
 The distribution algebra behind a snapshot swap is cached: the merged knot
 grid is computed once, ``iid_max(mix, bs)`` is one CDF-power per batch size
 off a shared knot-CDF, and per-(app, bs) drop-phase estimates are memoized.
@@ -40,7 +41,13 @@ from .distributions import (
     mixture,
 )
 from .hull import HullQueue
-from .priority import DEFAULT_B, RESET_EXPONENT, BinScoreModel, aggregate_steps
+from .priority import (
+    DEFAULT_B,
+    RESET_EXPONENT,
+    BinScoreModel,
+    StackedScoreModel,
+    aggregate_steps,
+)
 from .profiler import OnlineProfiler, ProfilerConfig
 from .request import PiecewiseStepCost, Request
 
@@ -86,6 +93,22 @@ def _score_flat(
     if seg_starts is None:
         return alpha, beta, milestone
     return aggregate_steps(alpha, beta, milestone, seg_starts)
+
+
+def _score_stacked(
+    model: StackedScoreModel,
+    deadlines: np.ndarray,
+    costs: np.ndarray,
+    seg_starts: np.ndarray | None,
+    t: float,
+    base: float,
+) -> list[tuple[np.ndarray, np.ndarray, np.ndarray]]:
+    """Per-request (α, β, milestone) arrays for every batch size, in the
+    stacked model's order, from one scoring pass."""
+    rows = list(zip(*model.score_many(deadlines, costs, t, base)))
+    if seg_starts is None:
+        return rows
+    return [aggregate_steps(a, b, m, seg_starts) for a, b, m in rows]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -215,7 +238,8 @@ class OrlojScheduler:
         expected latencies from the current app distributions (§4.3 — this
         is the heavy computation moved off the critical path).  One snapshot
         swap costs one mixture evaluation on the cached grid plus one CDF
-        power + hull-ready score model per batch size."""
+        power + hull-ready score model per batch size, stacked into the
+        one model that scores arrivals at every batch size."""
         with span("orloj.sched.rebuild"):
             mix = self._mixture()
             self._mix = mix
@@ -226,6 +250,9 @@ class OrlojScheduler:
                 batch_dist = self.latency_model.batch_dist(max_dist, bs)
                 st.score_model = BinScoreModel(batch_dist, b=self.cfg.b)
                 st.est_latency = self.latency_model.expected_batch_time(mix, bs)
+            self._stacked = StackedScoreModel(
+                [st.score_model for st in self._bs_state.values()]
+            )
 
     def estimate_batch_latency(self, req: Request, bs: int) -> float:
         """EstimateBatchLatency(r, bs) — Algorithm 1 line 11."""
@@ -258,7 +285,7 @@ class OrlojScheduler:
 
     def on_arrivals(self, reqs: Sequence[Request], now: float) -> None:
         """Bulk arrival: score every request at every batch size in one
-        vectorized Eq.-2 pass per batch size and insert the new lines as a
+        vectorized Eq.-2 pass and insert each batch size's new lines as a
         single hull block (the event loop coalesces same-timestamp
         arrivals into one call)."""
         with span("orloj.sched.on_arrivals"):
@@ -274,10 +301,10 @@ class OrlojScheduler:
                 self._feasible[rid] = set(all_bs)
             heap_entries = [(r.release + r.slo, r.rid) for r in reqs]
             self.n_scored += len(reqs) * len(self._bs_state)
-            for bs, st in self._bs_state.items():
-                alpha, beta, miles = _score_flat(
-                    st.score_model, deadlines, costs, seg_starts, now, self._base
-                )
+            rows = _score_stacked(
+                self._stacked, deadlines, costs, seg_starts, now, self._base
+            )
+            for (bs, st), (alpha, beta, miles) in zip(self._bs_state.items(), rows):
                 # simlint: ignore[R5] -- one bulk hull-block load per batch size (not per request); this *is* the PR-2 vectorized path replacing n scalar inserts
                 st.hull.insert_many(list(zip(rids, alpha.tolist(), beta.tolist())))
                 for entry in heap_entries:
@@ -321,8 +348,9 @@ class OrlojScheduler:
 
     def _recompute_all(self, now: float) -> None:
         """Full (α, β) refresh (base reset, snapshot swap): one vectorized
-        scoring pass per batch size + an O(n log n) hull bulk load, instead
-        of O(pending · |bs|) scalar scores with cascading block merges."""
+        scoring pass over every batch size + an O(n log n) hull bulk load
+        per batch size, instead of O(pending · |bs|) scalar scores with
+        cascading block merges."""
         with span("orloj.sched.recompute"):
             self._milestones.clear()
             reqs = list(self._pending.values())
@@ -333,10 +361,10 @@ class OrlojScheduler:
             deadlines, costs, seg_starts = _flatten_steps(reqs)
             rids = [r.rid for r in reqs]
             self.n_scored += len(reqs) * len(self._bs_state)
-            for bs, st in self._bs_state.items():
-                alpha, beta, miles = _score_flat(
-                    st.score_model, deadlines, costs, seg_starts, now, self._base
-                )
+            rows = _score_stacked(
+                self._stacked, deadlines, costs, seg_starts, now, self._base
+            )
+            for (bs, st), (alpha, beta, miles) in zip(self._bs_state.items(), rows):
                 lines = []
                 for rid, a, b_, m in zip(
                     rids, alpha.tolist(), beta.tolist(), miles.tolist()

@@ -6,6 +6,8 @@ The invariants (DESIGN.md §Hot-path):
   ``score`` (which is a thin wrapper over it) and with the literal-Eq.-2
   ``value_reference`` oracle to float tolerance, across all three regimes
   and for piecewise-step costs;
+- ``StackedScoreModel.score_many`` (every batch size in one pass) agrees
+  bit for bit with each batch size's ``score_many``;
 - ``HullQueue.insert_many`` / ``bulk_load`` produce an envelope identical
   to sequential ``insert``;
 - ``OrlojScheduler.on_arrivals`` leaves the scheduler in the same state as
@@ -179,6 +181,98 @@ def test_aggregate_steps_segments():
     assert list(a) == [3.0, 12.0]
     assert list(b) == [0.75, 0.1875]
     assert list(m) == [3.0, 7.0]
+
+
+# ---------------------------------------------------------- stacked scoring
+def _random_models(rng, bin_counts):
+    """One score model per batch size with profiler-like full-mantissa bin
+    edges, ``bin_counts[k]`` bins each (uneven counts exercise the padding)."""
+    models = []
+    for n_bins in bin_counts:
+        edges = np.sort(rng.uniform(0.3, 600.0, size=n_bins + 1))
+        probs = rng.dirichlet(np.ones(n_bins))
+        models.append(BinScoreModel(EmpiricalDistribution(edges, probs)))
+    return models
+
+
+def _assert_rows_bitwise(stacked, deadlines, costs, t, base):
+    got = stacked.score_many(deadlines, costs, t, base)
+    for k, m in enumerate(stacked.models):
+        want = m.score_many(deadlines, costs, t, base)
+        for g, w in zip(got, want):
+            assert np.array_equal(g[k], w), (k, t, base)
+
+
+@pytest.mark.parametrize("n", [1, 2, 37])
+def test_stacked_score_many_equals_each_batch_size_bitwise(n):
+    """Row k of the one-pass score equals batch size k's ``score_many``
+    bit for bit, over random deadlines, costs, t and base, with
+    histograms of different bin counts."""
+    from repro.core.priority import StackedScoreModel
+
+    rng = np.random.default_rng(n)
+    for _ in range(40):
+        stacked = StackedScoreModel(_random_models(rng, (24, 3, 12, 24, 7)))
+        t = float(rng.uniform(0.0, 1e6))
+        base = t - float(rng.uniform(0.0, 5e5))
+        deadlines = t + rng.uniform(-50.0, 900.0, size=n)
+        costs = rng.uniform(0.1, 50.0, size=n)
+        _assert_rows_bitwise(stacked, deadlines, costs, t, base)
+
+
+def test_stacked_score_many_slack_on_bin_edge():
+    """Slack exactly on an edge, and the wake instant ``t = fl(D − edge)``
+    where the naive next milestone lands on ``t`` itself (the ulp-coincident
+    step of ``_next_future``): still bit for bit per batch size."""
+    from repro.core.priority import StackedScoreModel
+
+    rng = np.random.default_rng(7)
+    ulp_cases = 0
+    for _ in range(30):
+        stacked = StackedScoreModel(_random_models(rng, (12, 24, 5)))
+        edges = np.unique(np.concatenate([m.l1 for m in stacked.models]
+                                         + [m.l2 for m in stacked.models]))
+        # slack == edge exactly: t = 0, D = edge
+        _assert_rows_bitwise(stacked, edges, np.ones_like(edges), 0.0, 0.0)
+        d = float(rng.uniform(1e5, 1e6))
+        for e in edges:
+            t = d - e
+            _assert_rows_bitwise(stacked, np.array([d, d + 1.0]),
+                                 np.array([1.0, 2.0]), t, t - 7.0)
+            for m in stacked.models:
+                j = np.searchsorted(m.l1, d - t, side="left")
+                ulp_cases += bool(j > 0 and d - m.l1[j - 1] <= t)
+    assert ulp_cases > 0  # the stepping loop ran
+
+
+def test_stacked_piecewise_steps_through_seg_starts():
+    """Appendix-B requests fold back per batch size through
+    ``aggregate_steps`` exactly as the per-batch-size path does."""
+    from repro.core.scheduler import _flatten_steps, _score_flat, _score_stacked
+
+    s = OrlojScheduler(LM, initial_dists=_dists())
+    reqs = [
+        _req(slo=400.0, cost=1.0, extra_deadlines=((600.0, 3.0), (900.0, 4.5))),
+        _req(slo=500.0),
+        _req(release=20.0, slo=250.0, cost=2.0, extra_deadlines=((700.0, 2.5),)),
+    ]
+    d, c, seg = _flatten_steps(reqs)
+    assert seg is not None
+    for t in (0.0, 150.0, 380.0, 450.0, 640.0, 880.0, 1_000.0):
+        rows = _score_stacked(s._stacked, d, c, seg, t, 0.0)
+        assert len(rows) == len(s._bs_state)
+        for row, st_ in zip(rows, s._bs_state.values()):
+            want = _score_flat(st_.score_model, d, c, seg, t, 0.0)
+            for g, w in zip(row, want):
+                assert np.array_equal(g, w), t
+
+
+def test_stacked_score_model_rejects_mixed_b():
+    from repro.core.priority import StackedScoreModel
+
+    d = EmpiricalDistribution(np.array([1.0, 2.0]), np.array([1.0]))
+    with pytest.raises(ValueError):
+        StackedScoreModel([BinScoreModel(d, b=1e-4), BinScoreModel(d, b=2e-4)])
 
 
 # ---------------------------------------------------------------- bulk hull

@@ -219,3 +219,57 @@ def test_profiler_feedback_loop_adapts():
     # the learned mixture must end up far from the wrong prior
     assert sched._mix.mean() > 10.0
     assert res.finish_rate > 0.5
+
+
+@pytest.mark.parametrize("engine", ["scalar", "array"])
+def test_stacked_scoring_keeps_every_decision(monkeypatch, engine):
+    """Scoring arrivals at every batch size in one stacked pass changes no
+    decision: on a virtual clock, the same bimodal trace gives the same
+    batches in the same order, the same drops and the same ``n_scored`` as
+    the per-batch-size ``score_many`` loop it replaced."""
+    from repro.core import scheduler as sched_mod
+
+    rs = generate_requests(
+        bimodal(1.0), LM, slo_scale=2.0,
+        cfg=TraceConfig(n_requests=1500, seed=5, utilization=0.95),
+    )
+
+    def run():
+        reqs = rs.fresh()
+        index = {r.rid: i for i, r in enumerate(reqs)}
+        sched = OrlojScheduler(LM, initial_dists=rs.initial_dists())
+        inner = ModelExecutor(LM)
+        batches = []
+
+        def execute(batch, now):
+            batches.append(
+                (now, batch.batch_size, [index[r.rid] for r in batch.requests])
+            )
+            return inner(batch, now)
+
+        res = simulate(reqs, sched, execute, engine=engine)
+        drops = [(i, r.dropped) for i, r in enumerate(reqs) if r.dropped is not None]
+        return batches, drops, res.n_scored
+
+    recomputes = []
+    full = OrlojScheduler._recompute_all
+    monkeypatch.setattr(
+        OrlojScheduler, "_recompute_all",
+        lambda self, now: recomputes.append(now) or full(self, now),
+    )
+    shipped = run()
+
+    def per_batch_size(model, deadlines, costs, seg_starts, t, base):
+        return [
+            sched_mod._score_flat(m, deadlines, costs, seg_starts, t, base)
+            for m in model.models
+        ]
+
+    monkeypatch.setattr(sched_mod, "_score_stacked", per_batch_size)
+    looped = run()
+    batches, drops, n_scored = shipped
+    # a run that batches, sheds and swaps profiler snapshots (full recompute)
+    assert len(batches) > 100 and drops and recomputes
+    assert batches == looped[0]
+    assert drops == looped[1]
+    assert n_scored == looped[2]
