@@ -25,15 +25,21 @@ from typing import Iterator
 from ..core import FileContext, Finding
 
 # (path suffix, qualified scope) — the PR-2 scheduler hot path plus the
-# PR-7 array event engine (the whole point of which is bulk columnar
-# work; a per-event container birth there is a regression)
+# event loop: its one body and the per-event code of its two event
+# sources (the array source's whole point is bulk columnar work; a
+# per-event container birth there is a regression)
+_SOURCE_METHODS = ("events", "push", "started", "finished", "unstarted", "alone")
 HOT_FUNCTIONS: tuple[tuple[str, str], ...] = (
     ("core/scheduler.py", "OrlojScheduler.on_arrivals"),
     ("core/scheduler.py", "OrlojScheduler.next_batch"),
     ("core/eventloop.py", "run_event_loop"),
     ("core/eventloop.py", "run_event_loop.try_dispatch"),
-    ("core/eventloop.py", "_array_loop"),
-    ("core/eventloop.py", "_array_loop.try_dispatch"),
+    ("core/eventloop.py", "run_event_loop.abort_batch"),
+    ("core/eventloop.py", "run_event_loop.credit_busy_until"),
+) + tuple(
+    ("core/eventloop.py", f"{source}.{method}")
+    for source in ("_HeapSource", "_ArraySource")
+    for method in _SOURCE_METHODS
 )
 
 _CTOR_CALLS = {"list", "dict", "set"}

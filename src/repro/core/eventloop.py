@@ -7,7 +7,10 @@ latency ``l_B = c0 + c1·k·max_r l_r`` per Eq. 3–4) and the replica-pool
 setting (§3.1: "different models and their replicas can use ORLOJ in
 parallel").  The 1-worker case *is* the classic ``simulate`` loop; the
 N-worker case adds a front-end dispatch policy that assigns each arriving
-request to a replica scheduler.
+request to a replica scheduler.  The loop body is written once; the
+``engine`` argument picks the event source behind it: the heapq source
+(``scalar``, the oracle) or the RequestStore + EventWheel source
+(``array``, DESIGN.md §10).
 
 Design points, each of which previously existed in only one of the two
 diverged copies of this loop:
@@ -54,7 +57,7 @@ import heapq
 import itertools
 import math
 import time as _time
-from typing import Callable, Protocol, Sequence
+from typing import Callable, Iterator, Protocol, Sequence
 
 import numpy as np
 
@@ -590,16 +593,270 @@ _CRASH, _RESTART, _RETRY, _ABORT = 3, 4, 5, 6
 # resumable execution — a DONE that may re-arm itself.
 _STEP = 7
 
-# Array-loop merge sources (where the next dynamic event comes from).
+# Array-source merge sources (where the next dynamic event comes from).
 _TAKE_BUF, _TAKE_BUCKET, _TAKE_ONE = 1, 2, 3
 _NO_EVENT = (math.inf, -1)
 
-# Event-loop implementations.  ``scalar`` is the original heapq loop and
-# stays the oracle; ``array`` is the array-backed engine (RequestStore +
-# EventWheel, DESIGN.md §10) whose observable behaviour — every scheduler
-# hook call, timestamp, rng draw and result field — is bit-identical to
-# the oracle (regression-tested over the full small grid).
+# Event sources behind the one loop body.  ``scalar`` is the heapq source
+# and stays the oracle; ``array`` is the RequestStore + EventWheel source
+# (DESIGN.md §10).  Every scheduler decision, timestamp, rng draw and
+# result field but ``peak_heap_size`` is bit-identical between them
+# (regression-tested over the full small grid).
 ENGINES = ("scalar", "array")
+
+
+class _HeapSource:
+    """The scalar engine's events: one heapq of ``(time, seq, kind,
+    payload)`` with one push per arrival, and request state written on the
+    :class:`Request` objects as it happens.  It never calls the columnar
+    delivery hooks, even when the scheduler has them."""
+
+    store = arrival_row = arrivals_cols = None
+    # Every arrival takes the per-request routing path, so the equivalence
+    # tests check the array source's one-worker shortcut against it.
+    one_worker_shortcut = False
+
+    def __init__(self, requests: Sequence[Request]) -> None:
+        self.requests = sorted(requests, key=lambda r: r.release)
+        self._seq = itertools.count()
+        self._heap: list[tuple[float, int, int, object]] = []
+        for r in self.requests:
+            heapq.heappush(self._heap, (r.release, next(self._seq), _ARRIVAL, r))
+        self.peak = len(self._heap)  # high-water mark of the heap
+
+    def push(self, t: float, kind: int, payload: object) -> None:
+        heap = self._heap
+        heapq.heappush(heap, (t, next(self._seq), kind, payload))
+        if len(heap) > self.peak:
+            self.peak = len(heap)
+
+    def events(self) -> Iterator[tuple[float, int, object]]:
+        """``(time, kind, payload)`` in ``(time, seq)`` order.  Every
+        arrival bearing one timestamp comes out as one group
+        ``(requests, 0, k)``."""
+        heap = self._heap
+        while heap:
+            now, _, kind, payload = heapq.heappop(heap)
+            if kind == _ARRIVAL:
+                # simlint: ignore[R5] -- one burst buffer per ARRIVAL event; the coalescing is what enables the bulk on_arrivals path
+                group = [payload]
+                while heap and heap[0][0] == now and heap[0][2] == _ARRIVAL:
+                    group.append(heapq.heappop(heap)[3])
+                payload = (group, 0, len(group))
+            yield now, kind, payload
+
+    def started(self, reqs: Sequence[Request], rows, t: float) -> None:
+        for r in reqs:
+            r.started = t
+
+    def finished(self, reqs: Sequence[Request], rows, t: float) -> None:
+        for r in reqs:
+            r.finished = t
+
+    def unstarted(self, reqs: Sequence[Request], rows) -> None:
+        for r in reqs:
+            r.started = None
+
+    def alone(self, reqs: Sequence[Request], rows) -> list[float]:
+        # simlint: ignore[R5] -- one alone-times list per completed batch (feedback path), not per request
+        return [r.true_time for r in reqs]
+
+    def fold(self, n_off_ledger: int):
+        """``(ok, late, dropped, unserved, latencies)`` from the objects.
+        Unserved is scanned, not derived, so the conservation invariant
+        stays a real, falsifiable property."""
+        requests = self.requests
+        ok = sum(1 for r in requests if r.ok)
+        late = sum(1 for r in requests if r.finished is not None and not r.ok)
+        dropped = sum(1 for r in requests if r.dropped is not None)
+        unserved = sum(
+            1
+            for r in requests
+            if r.finished is None and r.dropped is None
+            and r.rejected is None and r.failed is None
+        )
+        lat = np.array(
+            [r.finished - r.release for r in requests if r.finished is not None]
+        )
+        return ok, late, dropped, unserved, lat
+
+
+def _wheel_width(group_times: Sequence[float]) -> float | None:
+    """Bucket width for the DONE/WAKE wheel: a few mean arrival-group gaps
+    (batch completions land roughly once per served burst of arrivals), or
+    ``None`` → pure-heapq mode when the trace gives no usable spread."""
+    if len(group_times) < 2:
+        return None
+    span = group_times[-1] - group_times[0]
+    if not (span > 0.0) or not math.isfinite(span):
+        return None
+    return 4.0 * span / (len(group_times) - 1)
+
+
+class _ArraySource:
+    """The array engine's events, built for 10⁵–10⁶-request traces:
+
+    - arrivals never touch a priority queue: the
+      :class:`~repro.core.requeststore.RequestStore` presorts the trace
+      into numpy columns with same-timestamp group boundaries, and the
+      arrival cursor hands out row ranges (seqs ``0..n-1``);
+    - DONE/WAKE and the other dynamic events live in the
+      :class:`~repro.core.eventwheel.EventWheel` and are drained a bucket
+      at a time; a three-way merge (arrival cursor, in-hand bucket batch,
+      wheel head) keeps the heap source's ``(time, seq)`` order;
+    - ``started``/``finished`` go to the store's columns once per batch.
+      The objects are written too only when a scheduler in the pool reads
+      them mid-run (``reads_request_state``, unknown schedulers default to
+      True); otherwise ``store.writeback()`` flushes the columns at the end.
+
+    With one worker and no admission gate, an arrival group skips the
+    per-request routing: its idle prefix is delivered one request at a
+    time and its busy tail as one slice.  With one worker, arrivals reach
+    a scheduler exposing them through the columnar hooks:
+    ``on_arrival_row(store, row, now)`` on the idle path and
+    ``on_arrivals_cols(store, lo, hi, now)`` for the busy tail.
+    ``peak`` counts pending events: undelivered arrivals plus the wheel.
+    """
+
+    one_worker_shortcut = True
+
+    def __init__(self, requests: Sequence[Request], workers: list[Worker]):
+        store = self.store = RequestStore(requests)
+        self.requests = store.requests
+        self.wheel = EventWheel(bucket_ms=_wheel_width(store.group_times))
+        n = len(store)
+        # dynamic events count on from the arrivals' seqs, so at equal
+        # times arrivals sort first, as in the heap
+        self._seq = itertools.count(n)
+        self.arr_left = n  # arrivals not yet delivered to a scheduler
+        self.peak = n
+        self._workers = workers
+        self.live = any(
+            getattr(w.scheduler, "reads_request_state", True) for w in workers
+        )
+        one = workers[0].scheduler if len(workers) == 1 else None
+        self.arrival_row = getattr(one, "on_arrival_row", None)
+        self.arrivals_cols = getattr(one, "on_arrivals_cols", None)
+
+    def push(self, t: float, kind: int, payload: object) -> None:
+        wheel = self.wheel
+        wheel.push(t, next(self._seq), kind, payload)
+        pending = self.arr_left + len(wheel)
+        if pending > self.peak:
+            self.peak = pending
+
+    def events(self) -> Iterator[tuple[float, int, object]]:
+        """``(time, kind, payload)`` in ``(time, seq)`` order; an arrival
+        group comes out as ``(store.requests, lo, hi)``."""
+        wheel = self.wheel
+        reqs = self.requests
+        gstarts = self.store.group_starts
+        gtimes = self.store.group_times
+        ng = len(gtimes)
+        gi = 0  # next arrival group
+        buf: list = []  # in-hand wheel bucket (drained, partially consumed)
+        bi = nbuf = 0
+        while True:
+            t_arr = gtimes[gi] if gi < ng else math.inf
+            if bi < nbuf:
+                ev = buf[bi]
+                ekey = (ev[0], ev[1])
+                take = _TAKE_BUF
+                if wheel:
+                    wkey = wheel.peek_key()
+                    if wkey < ekey:
+                        # an event pushed *during* the current bucket batch
+                        # landed before its remaining entries: take it singly
+                        ekey = wkey
+                        take = _TAKE_ONE
+            elif wheel:
+                ekey = wheel.peek_key()
+                take = _TAKE_BUCKET
+            else:
+                ekey = _NO_EVENT
+                take = 0
+            if t_arr <= ekey[0]:
+                if t_arr == math.inf:
+                    return  # arrivals, bucket batch and wheel all exhausted
+                a, b = gstarts[gi], gstarts[gi + 1]
+                gi += 1
+                self.arr_left -= b - a
+                yield t_arr, _ARRIVAL, (reqs, a, b)
+                continue
+            if take == _TAKE_BUF:
+                bi += 1
+            elif take == _TAKE_BUCKET:
+                # refill the in-hand batch with the next wheel bucket: one
+                # calendar-bucket drain amortizes the queue maintenance
+                # over every event in the bucket
+                buf = wheel.pop_bucket()
+                bi = 1
+                nbuf = len(buf)
+                ev = buf[0]
+            else:  # _TAKE_ONE
+                ev = wheel.pop()
+            yield ev[0], ev[2], ev[3]
+
+    def started(self, reqs: Sequence[Request], rows, t: float):
+        """Write ``t`` into the batch's rows; returns the rows as a range
+        or an index array."""
+        if rows is None:
+            # simlint: ignore[R5] -- one row-index list per dispatched batch: the price of one fancy-indexed column write replacing per-request attribute churn
+            rows = self.store.rows_for(reqs)
+        if type(rows) is range and rows.step == 1:
+            # rows-annotated batch (``on_arrivals_cols`` schedulers): the
+            # column write is an O(1) slice assignment
+            self.store.started[rows.start:rows.stop] = t
+        else:
+            rows = np.asarray(rows, dtype=np.intp)
+            self.store.started[rows] = t
+        if self.live:
+            for r in reqs:
+                r.started = t
+        return rows
+
+    def finished(self, reqs: Sequence[Request], rows, t: float) -> None:
+        if not reqs:
+            return
+        if type(rows) is range:
+            self.store.finished[rows.start:rows.stop] = t
+        else:
+            self.store.finished[rows] = t
+        if self.live:
+            for r in reqs:
+                r.finished = t
+
+    def unstarted(self, reqs: Sequence[Request], rows) -> None:
+        # writeback must not resurrect a phantom start
+        if type(rows) is range:
+            self.store.started[rows.start:rows.stop] = np.nan
+        else:
+            self.store.started[np.asarray(rows, dtype=np.intp)] = np.nan
+        if self.live:
+            for r in reqs:
+                r.started = None
+
+    def alone(self, reqs: Sequence[Request], rows) -> list[float]:
+        if type(rows) is range:
+            return self.store.true_time[rows.start:rows.stop].tolist()
+        return self.store.true_time[rows].tolist()
+
+    def fold(self, n_off_ledger: int):
+        """``(ok, late, dropped, unserved, latencies)`` folded from the
+        columns, bit-identical to the heap source's pass over the objects."""
+        if not self.live:
+            self.store.writeback()
+        # Drop-free fast path: every ``req.dropped = ...`` write in the
+        # repo's schedulers is paired with an ``n_timed_out`` increment, so
+        # a pool whose schedulers all expose the counter at zero provably
+        # dropped nothing and the O(n) per-object dropped scan is skipped.
+        no_drops = all(
+            getattr(w.scheduler, "n_timed_out", None) == 0
+            for w in self._workers
+        )
+        return self.store.fold_stats(
+            no_drops=no_drops, n_off_ledger=n_off_ledger
+        )
 
 
 def run_event_loop(
@@ -633,34 +890,33 @@ def run_event_loop(
     overhead study: with ms-scale requests, scheduling time itself starts
     to matter).
 
-    ``engine`` picks the implementation (:data:`ENGINES`): ``"scalar"`` is
-    the original heapq loop (the oracle); ``"array"`` sources arrivals from
-    a :class:`~repro.core.requeststore.RequestStore` and DONE/WAKE events
-    from an :class:`~repro.core.eventwheel.EventWheel` — same observable
-    behaviour, built for 10⁵–10⁶-request traces.  ``peak_heap_size`` is the
-    one intentionally engine-specific field: both report peak *pending
-    events*, but the scalar heap retains superseded-wake tombstones
-    slightly differently than the wheel, so only the bound (not the exact
-    value) is comparable.
+    ``engine`` picks the event source (:data:`ENGINES`) behind the one
+    loop body: ``"scalar"`` is the heapq source (the oracle); ``"array"``
+    sources arrivals from a :class:`~repro.core.requeststore.RequestStore`
+    and the dynamic events from an
+    :class:`~repro.core.eventwheel.EventWheel`, built for 10⁵–10⁶-request
+    traces.  ``peak_heap_size`` is the one engine-specific field: both
+    report peak *pending events*, but the heap retains superseded-wake
+    tombstones slightly differently than the wheel, so only the bound
+    (not the exact value) is comparable.
 
     ``faults`` is an optional :class:`~repro.serving.faults.FaultPlan`
     (anything exposing ``start(n_workers)``): worker crashes, stragglers,
-    admission control and batch timeouts, replayed identically by both
-    engines from the plan's own seeded rng streams (DESIGN.md §11).
-    ``wall_budget_s > 0`` cuts the run off after that much *wall-clock*
-    time: the result is marked ``truncated`` and everything unresolved
-    counts as unserved — a graceful partial answer instead of a hung grid
-    cell.
+    admission control and batch timeouts, replayed from the plan's own
+    seeded rng streams (DESIGN.md §11).  ``wall_budget_s > 0`` cuts the
+    run off after that much *wall-clock* time: the result is marked
+    ``truncated`` and everything unresolved counts as unserved — a
+    graceful partial answer instead of a hung grid cell.
 
     ``residency`` is an optional
     :class:`~repro.serving.residency.ResidencyPlan`: per-worker weights
     caches for multi-model serving (DESIGN.md §13).  Every dispatched
     batch must then carry ``Batch.model``; a cache miss stalls execution
-    by the model's load time (plus eviction costs), charged identically
-    by both engines.  ``residency=None`` (every single-model run) takes
-    zero new branches — the ``single-model-noop`` claim gates this
-    bitwise.  Residency composes with neither fault injection nor decode
-    batches (both raise ``ValueError``, the pinned unsupported seams).
+    by the model's load time (plus eviction costs).  ``residency=None``
+    (every single-model run) takes zero new branches — the
+    ``single-model-noop`` claim gates this bitwise.  Residency composes
+    with neither fault injection nor decode batches (both raise
+    ``ValueError``, the pinned unsupported seams).
     """
     workers = list(workers)
     if not workers:
@@ -705,26 +961,14 @@ def run_event_loop(
                     "fault injection (crashes/batch timeouts) requires "
                     "schedulers exposing n_timed_out"
                 )
-    if engine == "array":
-        return _array_loop(
-            requests,
-            workers,
-            pool,
-            pick,
-            horizon=horizon,
-            charge_scheduler_overhead=charge_scheduler_overhead,
-            fs=fs,
-            res=res,
-            wall_budget_s=wall_budget_s,
-        )
 
     scored0 = _n_scored(workers)
-    requests = sorted(requests, key=lambda r: r.release)
-    events: list[tuple[float, int, int, object]] = []
-    seq = itertools.count()
-    for r in requests:
-        heapq.heappush(events, (r.release, next(seq), _ARRIVAL, r))
-
+    src = (
+        _HeapSource(requests) if engine == "scalar"
+        else _ArraySource(requests, workers)
+    )
+    n_total = len(src.requests)
+    push, started, finished = src.push, src.started, src.finished
     plan = fs.plan if fs is not None else None
     n_rejected = 0
     n_failed = 0
@@ -736,20 +980,16 @@ def run_event_loop(
     # batch was dispatched under; a crash bumps the epoch so the stale
     # completion becomes a tombstone when it fires.
     epoch = [0] * n
-    # In-flight batch payloads, maintained only under a fault plan (the
-    # crash-abort path needs the batch; ``inflight`` keeps only spans).
-    running: list[Batch | None] = [None] * n
+    # In-flight (batch, rows) payloads, maintained only under a fault plan
+    # (the crash-abort path needs the batch; ``inflight`` keeps only spans).
+    running: list[tuple[Batch, object] | None] = [None] * n
     gate = plan is not None and plan.admission_floor > 0.0
     timeout_ms = plan.batch_timeout_ms if plan is not None else 0.0
     if fs is not None and fs.crashes:
-        # initial crash draws, one per worker in index order (the array
-        # loop mirrors this exactly, so seq numbers line up)
+        # initial crash draws, one per worker in index order
         for w in range(n):
-            heapq.heappush(
-                events, (fs.next_crash(w, 0.0), next(seq), _CRASH, w)
-            )
+            push(fs.next_crash(w, 0.0), _CRASH, w)
 
-    peak_heap = len(events)
     worker_busy_time = 0.0
     sched_time = 0.0  # wall-clock seconds inside scheduler hooks
     n_decisions = 0
@@ -757,23 +997,41 @@ def run_event_loop(
     last_time = 0.0
     inflight: list[tuple[float, float] | None] = [None] * n  # (start, end)
     # At most one *live* WAKE per worker (re-armed only for an earlier
-    # wake): the dedup that keeps the heap from flooding under light load.
+    # wake): the dedup that keeps the queue from flooding under light load.
     pending_wake: list[float | None] = [None] * n
+    busy = pool.busy
+    pc = _time.perf_counter
+    delivers = [getattr(w.scheduler, "on_arrivals", None) for w in workers]
+    sched0 = workers[0].scheduler
+    store, arrival_row, arrivals_cols = (
+        src.store, src.arrival_row, src.arrivals_cols
+    )
+    shortcut = n == 1 and not gate and src.one_worker_shortcut
 
     def try_dispatch(w: int, now: float) -> None:
-        nonlocal worker_busy_time, peak_heap, sched_time, n_decisions
-        if pool.busy[w] or down[w]:
+        nonlocal worker_busy_time, sched_time, n_decisions
+        if busy[w] or down[w]:
             return
         worker = workers[w]
         # simlint: ignore[R1] -- meters real scheduler overhead (reported, optionally charged as latency); the sim clock itself stays virtual
-        t0 = _time.perf_counter()
+        t0 = pc()
         batch, wake = worker.scheduler.next_batch(now)
         # simlint: ignore[R1] -- closes the overhead meter opened above
-        dt = _time.perf_counter() - t0
+        dt = pc() - t0
         sched_time += dt
         n_decisions += 1
-        overhead = dt * 1e3 if charge_scheduler_overhead else 0.0
-        if batch is not None and getattr(batch, "decode", False):
+        if batch is None:
+            if wake is not None and np.isfinite(wake) and wake > now:
+                if pending_wake[w] is None or wake < pending_wake[w]:
+                    pending_wake[w] = wake
+                    push(wake, _WAKE, w)
+            # the decision may have timed requests out (drop phase) — keep
+            # the policy load signal honest
+            pool.sweep_dropped(w)
+            return
+        start = now + (dt * 1e3 if charge_scheduler_overhead else 0.0)
+        stall = 0.0
+        if getattr(batch, "decode", False):
             # Resumable token-level execution (DESIGN.md §12): the dispatch
             # step prefills every initial member and produces their first
             # token; the run then re-arms _STEP events until the last
@@ -788,23 +1046,15 @@ def run_event_loop(
                     "decode (token-level) batches are not supported "
                     "under multi-model residency"
                 )
-            start = now + overhead
             run = _DecodeRun(batch, list(batch.requests), None)
             dur = _decode_step_dur(
                 worker.executor, run.active, batch.requests, start
             )
-            for r in batch.requests:
-                r.started = start
-                pool.discharge(w, r.rid)
-            pool.busy[w] = True
-            worker_busy_time += dur
-            inflight[w] = (start, start + dur)
-            heapq.heappush(
-                events, (start + dur, next(seq), _STEP, (w, run, epoch[w]))
-            )
-            peak_heap = max(peak_heap, len(events))
-        elif batch is not None:
-            start = now + overhead
+            rows = started(batch.requests, batch.rows, start)
+            if rows is not None:
+                run.rows = [int(x) for x in rows]
+            kind, payload = _STEP, (w, run, epoch[w])
+        else:
             if res is not None:
                 # Weights residency (DESIGN.md §13): a cache miss stalls
                 # the batch by the load time (plus eviction costs) before
@@ -817,35 +1067,26 @@ def run_event_loop(
                     )
                 stall = res.acquire(w, batch.model, start)
                 start += stall
-            else:
-                stall = 0.0
             dur = worker.executor(batch, start)
-            ev_kind = _DONE
+            kind = _DONE
             if fs is not None:
                 dur = fs.straggle(dur)
                 if 0.0 < timeout_ms < dur:
-                    # overlong batch: aborted at the timeout deadline,
-                    # its requests go through the retry gate
+                    # overlong batch: aborted at the timeout deadline, its
+                    # requests go through the retry gate
                     dur = timeout_ms
-                    ev_kind = _ABORT
-                running[w] = batch
+                    kind = _ABORT
+            rows = started(batch.requests, batch.rows, start)
+            if fs is not None:
+                running[w] = (batch, rows)
+            payload = (w, batch, rows, epoch[w])
+        if track_work:
             for r in batch.requests:
-                r.started = start
                 pool.discharge(w, r.rid)
-            pool.busy[w] = True
-            worker_busy_time += stall + dur
-            inflight[w] = (start - stall, start + dur)
-            heapq.heappush(
-                events, (start + dur, next(seq), ev_kind, (w, batch, epoch[w]))
-            )
-            peak_heap = max(peak_heap, len(events))
-        elif wake is not None and np.isfinite(wake) and wake > now:
-            if pending_wake[w] is None or wake < pending_wake[w]:
-                pending_wake[w] = wake
-                heapq.heappush(events, (wake, next(seq), _WAKE, w))
-                peak_heap = max(peak_heap, len(events))
-        # the decision may have timed requests out (drop phase) — keep the
-        # policy load signal honest
+        busy[w] = True
+        worker_busy_time += stall + dur
+        inflight[w] = (start - stall, start + dur)
+        push(start + dur, kind, payload)
         pool.sweep_dropped(w)
 
     def work_remains() -> bool:
@@ -855,87 +1096,102 @@ def run_event_loop(
         resolved = n_finished + n_rejected + n_failed
         for w_ in workers:
             resolved += w_.scheduler.n_timed_out  # type: ignore[attr-defined]
-        return resolved < len(requests)
+        return resolved < n_total
 
-    def abort_batch(w: int, batch: Batch, now: float) -> None:
+    def abort_batch(w: int, batch: Batch, rows, now: float) -> None:
         # Crash/timeout abort: each request re-enters through the
         # deadline-aware retry gate or terminates honestly as failed.
-        nonlocal n_failed, n_retried, peak_heap
+        nonlocal n_failed, n_retried
         assert fs is not None
+        src.unstarted(batch.requests, rows)
         sched = workers[w].scheduler
         for r in batch.requests:
-            r.started = None
             retry, t_retry = fs.retry_decision(sched, r, now)
             if retry:
                 r.retries += 1
                 n_retried += 1
-                heapq.heappush(events, (t_retry, next(seq), _RETRY, r))
+                push(t_retry, _RETRY, r)
             else:
                 r.failed = now
                 n_failed += 1
-        peak_heap = max(peak_heap, len(events))
+
+    def credit_busy_until(t: float) -> None:
+        # stop observing at ``t``: in-flight batches are credited only
+        # for the work done before it (their requests stay unserved)
+        nonlocal worker_busy_time
+        for span in inflight:
+            if span is not None and span[1] > t:
+                worker_busy_time -= span[1] - max(span[0], t)
 
     wall_deadline = None
     if wall_budget_s > 0.0:
         # simlint: ignore[R1] -- wall-budget truncation is real elapsed time by design; the sim clock stays virtual
-        wall_deadline = _time.perf_counter() + wall_budget_s
+        wall_deadline = pc() + wall_budget_s
     n_events = 0
-    while events:
-        now, _, kind, payload = heapq.heappop(events)
+    for now, kind, payload in src.events():
         n_events += 1
         if (
             wall_deadline is not None
             and not n_events & 1023
             # simlint: ignore[R1] -- wall-budget truncation check (real elapsed time by design)
-            and _time.perf_counter() > wall_deadline
+            and pc() > wall_deadline
         ):
             # Out of wall-clock budget: stop observing at the last
-            # processed event (the popped one is discarded unprocessed),
-            # clamp in-flight busy credit exactly like the horizon path,
+            # processed event (the popped one is discarded unprocessed)
             # and report the partial stats as ``truncated``.
             truncated = True
-            for span in inflight:
-                if span is not None and span[1] > last_time:
-                    worker_busy_time -= span[1] - max(span[0], last_time)
+            credit_busy_until(last_time)
             break
         if horizon is not None and now > horizon:
             # Stop observing at the horizon: the clock reads ``horizon``
-            # (not the time of the first event beyond it) and busy time is
-            # only credited for work inside the window — an in-flight
-            # batch's requests stay unserved, so crediting its full
-            # duration would overstate utilization.
+            # (not the time of the first event beyond it).
             last_time = horizon
-            for span in inflight:
-                if span is not None and span[1] > horizon:
-                    worker_busy_time -= span[1] - max(span[0], horizon)
+            credit_busy_until(horizon)
             break
         last_time = now
         if kind == _ARRIVAL:
-            # Coalesce every arrival bearing this exact timestamp (a burst
-            # drained from the network in one go).  While a worker is idle
-            # its share is delivered one request at a time with a dispatch
-            # attempt in between — identical to the pre-coalescing loop, so
-            # an urgent head-of-burst request can still grab the idle
-            # worker.  The moment the worker goes busy (the high-load hot
-            # path) the rest of the burst is delivered as ONE bulk
-            # ``on_arrivals`` call and scored in a single vectorized pass.
-            # simlint: ignore[R5] -- one burst buffer per ARRIVAL event; the coalescing is what enables the bulk on_arrivals path
-            arrivals: list[Request] = [payload]
-            while events and events[0][0] == now and events[0][2] == _ARRIVAL:
-                arrivals.append(heapq.heappop(events)[3])
-            # Route/deliver in arrival order, exactly as the pre-coalescing
-            # loop did: an arrival routed to an IDLE worker is delivered and
-            # dispatched immediately (so an urgent head-of-burst request can
-            # grab the worker, and later picks see the dispatch's busy/
-            # discharge side effects).  Only arrivals routed to a BUSY
-            # worker — where a dispatch attempt would be a no-op anyway —
-            # are buffered and flushed as ONE bulk ``on_arrivals`` call,
-            # the high-load case where the vectorized scoring pass pays.
-            # ``pending_offset`` keeps count-based policies seeing buffered
-            # requests as if they were already delivered.
+            # One group of arrivals bearing this exact timestamp (a burst
+            # drained from the network in one go), delivered in arrival
+            # order: an arrival routed to an IDLE worker is delivered and
+            # dispatched immediately (so an urgent head-of-burst request
+            # can grab the worker, and later picks see the dispatch's
+            # busy/discharge side effects).  Arrivals routed to a BUSY
+            # worker, where a dispatch attempt would be a no-op, are
+            # delivered as ONE bulk call per worker, the high-load case
+            # where the vectorized scoring pass pays.
+            reqs, a, b = payload
+            if shortcut:
+                # One worker, no admission gate: no picks, no charges, and
+                # once the worker is busy the rest of the group is one
+                # slice (a row range for a columnar scheduler).
+                i = a
+                while i < b and not busy[0]:
+                    t0 = pc()  # simlint: ignore[R1] -- overhead meter, not sim time
+                    if arrival_row is not None:
+                        arrival_row(store, i, now)
+                    else:
+                        sched0.on_arrival(reqs[i], now)
+                    sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
+                    i += 1
+                    try_dispatch(0, now)
+                if i < b:
+                    t0 = pc()  # simlint: ignore[R1] -- overhead meter, not sim time
+                    if arrivals_cols is not None:
+                        arrivals_cols(store, i, b, now)
+                    elif delivers[0] is not None:
+                        # simlint: ignore[R5] -- one slice per (burst, busy) window, replacing per-request scheduler calls
+                        delivers[0](reqs[i:b], now)
+                    else:
+                        for req in reqs[i:b]:
+                            sched0.on_arrival(req, now)
+                    sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
+                continue
+            # ``pending_offset`` keeps count-based policies and the gate
+            # seeing buffered requests as if they were already delivered.
             # simlint: ignore[R5] -- one routing buffer per burst, replacing per-request scheduler calls with one bulk delivery per worker
             buffered: dict[int, list[Request]] = {}
-            for req in arrivals:
+            for i in range(a, b):
+                req = reqs[i]
                 w = pick(req, now, pool) if n > 1 else 0
                 if gate and not fs.admit(
                     workers[w].scheduler,
@@ -945,7 +1201,7 @@ def run_event_loop(
                     # burst share buffered for it, and the in-flight batch
                     getattr(workers[w].scheduler, "n_pending", 0)
                     + pool.pending_offset[w]
-                    + (1 if pool.busy[w] else 0),
+                    + (1 if busy[w] else 0),
                 ):
                     # shed at the front door: never queued, never charged
                     # (the pick above still ran, so the policy rng stream
@@ -954,684 +1210,28 @@ def run_event_loop(
                     n_rejected += 1
                     continue
                 pool.charge(w, req)
-                if pool.busy[w]:
+                if busy[w]:
                     # simlint: ignore[R5] -- group list created once per (burst, worker), not per request
                     buffered.setdefault(w, []).append(req)
                     pool.pending_offset[w] += 1
+                    continue
+                t0 = pc()  # simlint: ignore[R1] -- overhead meter, not sim time
+                if arrival_row is not None:
+                    arrival_row(store, i, now)
                 else:
-                    t0 = _time.perf_counter()  # simlint: ignore[R1] -- overhead meter, not sim time
                     workers[w].scheduler.on_arrival(req, now)
-                    sched_time += _time.perf_counter() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
-                    try_dispatch(w, now)
+                sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
+                try_dispatch(w, now)
             for w, group in buffered.items():
                 pool.pending_offset[w] = 0
-                sched = workers[w].scheduler
-                deliver = getattr(sched, "on_arrivals", None)
-                t0 = _time.perf_counter()  # simlint: ignore[R1] -- overhead meter, not sim time
-                if deliver is not None:
-                    deliver(group, now)
+                t0 = pc()  # simlint: ignore[R1] -- overhead meter, not sim time
+                if delivers[w] is not None:
+                    delivers[w](group, now)
                 else:
                     for req in group:
-                        sched.on_arrival(req, now)
-                sched_time += _time.perf_counter() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
-        elif kind == _DONE:
-            w, batch, ep = payload
-            if ep != epoch[w]:
-                continue  # tombstone: the worker crashed under this batch
-            pool.busy[w] = False
-            inflight[w] = None
-            if fs is not None:
-                running[w] = None
-            n_batches += 1
-            n_finished += len(batch.requests)
-            for r in batch.requests:
-                r.finished = now
-            t0 = _time.perf_counter()  # simlint: ignore[R1] -- overhead meter, not sim time
-            workers[w].scheduler.on_batch_done(
-                # simlint: ignore[R5] -- one alone-times list per completed batch (feedback path), not per request
-                batch, now, [r.true_time for r in batch.requests]
-            )
-            sched_time += _time.perf_counter() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
-            try_dispatch(w, now)
-        elif kind == _STEP:
-            # One decode iteration of a resumable execution: advance token
-            # counts, retire EOS requests, let the scheduler admit joiners
-            # at this token boundary, then re-arm (or drain the run).
-            w, run, ep = payload
-            if ep != epoch[w]:
-                continue  # tombstone (decode runs never coexist with faults today, but keep the contract uniform)
-            finished, _ = _advance_decode(run, now)
-            n_finished += len(finished)
-            for r in finished:
-                r.finished = now
-            t0 = _time.perf_counter()  # simlint: ignore[R1] -- overhead meter, not sim time
-            joined = workers[w].scheduler.on_decode_step(
-                finished, len(run.active), now
-            )
-            sched_time += _time.perf_counter() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
-            n_decisions += 1
-            if joined:
-                for r in joined:
-                    r.started = now
-                    pool.discharge(w, r.rid)
-                run.active.extend(joined)
-            if run.active:
-                dur = _decode_step_dur(
-                    workers[w].executor, run.active, joined, now
-                )
-                worker_busy_time += dur
-                inflight[w] = (now, now + dur)
-                heapq.heappush(
-                    events, (now + dur, next(seq), _STEP, (w, run, ep))
-                )
-                peak_heap = max(peak_heap, len(events))
-            else:
-                n_batches += 1
-                pool.busy[w] = False
-                inflight[w] = None
-                try_dispatch(w, now)
-            # the admission hook may also have timed requests out
-            pool.sweep_dropped(w)
-        elif kind == _WAKE:
-            w = payload
-            if pending_wake[w] is not None and now >= pending_wake[w]:
-                pending_wake[w] = None
-            try_dispatch(w, now)
-        elif kind == _ABORT:
-            w, batch, ep = payload
-            if ep != epoch[w]:
-                continue  # the worker crashed before the timeout fired
-            pool.busy[w] = False
-            inflight[w] = None
-            running[w] = None
-            abort_batch(w, batch, now)
-            try_dispatch(w, now)
-        elif kind == _CRASH:
-            w = payload
-            if work_remains():
-                # Kill the worker: bump its epoch (outstanding DONE/ABORT
-                # events become tombstones), abort any in-flight batch,
-                # schedule the restart.  With no work left the crash is
-                # discarded and nothing is rescheduled, so the heap
-                # drains and the loop terminates.
-                epoch[w] += 1
-                down[w] = True
-                span = inflight[w]
-                if span is not None:
-                    # credit only the work actually done before the crash
-                    worker_busy_time -= span[1] - max(span[0], now)
-                    inflight[w] = None
-                    pool.busy[w] = False
-                    doomed = running[w]
-                    running[w] = None
-                    assert doomed is not None
-                    abort_batch(w, doomed, now)
-                heapq.heappush(
-                    events,
-                    (now + plan.restart_delay_ms, next(seq), _RESTART, w),
-                )
-                peak_heap = max(peak_heap, len(events))
-        elif kind == _RESTART:
-            w = payload
-            down[w] = False
-            if work_remains():
-                heapq.heappush(
-                    events, (fs.next_crash(w, now), next(seq), _CRASH, w)
-                )
-                peak_heap = max(peak_heap, len(events))
-            try_dispatch(w, now)
-        else:  # _RETRY
-            req = payload
-            w = pick(req, now, pool) if n > 1 else 0
-            if down[w]:
-                # Dead-target re-route: deterministically drain to the
-                # next live sibling (fleet mode — a dead pool's requeued
-                # work flows across pool boundaries).  All-dead keeps the
-                # original target: it queues and the restart drains it.
-                for k in range(1, n):
-                    w2 = (w + k) % n
-                    if not down[w2]:
-                        w = w2
-                        break
-            pool.charge(w, req)
-            t0 = _time.perf_counter()  # simlint: ignore[R1] -- overhead meter, not sim time
-            workers[w].scheduler.on_arrival(req, now)
-            sched_time += _time.perf_counter() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
-            try_dispatch(w, now)
-
-    ok = sum(1 for r in requests if r.ok)
-    late = sum(1 for r in requests if r.finished is not None and not r.ok)
-    dropped = sum(1 for r in requests if r.dropped is not None)
-    # Unserved = no terminal state at all; scanned (not derived) so the
-    # conservation invariant stays a real, falsifiable property.
-    unserved = sum(
-        1
-        for r in requests
-        if r.finished is None and r.dropped is None
-        and r.rejected is None and r.failed is None
-    )
-    lat = np.array(
-        [r.finished - r.release for r in requests if r.finished is not None]
-    )
-    return SimResult(
-        n_total=len(requests),
-        n_finished_ok=ok,
-        n_finished_late=late,
-        n_dropped=dropped,
-        n_unserved=unserved,
-        worker_busy=worker_busy_time,
-        makespan_ms=last_time,
-        latencies=lat,
-        n_workers=n,
-        peak_heap_size=peak_heap,
-        sched_time_ms=sched_time * 1e3,
-        n_decisions=n_decisions,
-        n_batches=n_batches,
-        n_rejected=n_rejected,
-        n_failed=n_failed,
-        n_retried=n_retried,
-        truncated=truncated,
-        n_model_loads=res.n_loads if res is not None else 0,
-        n_model_evicts=res.n_evicts if res is not None else 0,
-        model_load_ms=res.load_ms_total if res is not None else 0.0,
-        n_scored=_n_scored(workers) - scored0,
-    )
-
-
-def _n_scored(workers: Sequence[Worker]) -> int:
-    """The ``n_scored`` counters of the pool's schedulers, each scheduler
-    once (0 for a scheduler without the counter)."""
-    scheds = {id(w.scheduler): w.scheduler for w in workers}
-    return sum(getattr(s, "n_scored", 0) for s in scheds.values())
-
-
-def _wheel_width(group_times: Sequence[float]) -> float | None:
-    """Bucket width for the DONE/WAKE wheel: a few mean arrival-group gaps
-    (batch completions land roughly once per served burst of arrivals), or
-    ``None`` → pure-heapq mode when the trace gives no usable spread."""
-    if len(group_times) < 2:
-        return None
-    span = group_times[-1] - group_times[0]
-    if not (span > 0.0) or not math.isfinite(span):
-        return None
-    return 4.0 * span / (len(group_times) - 1)
-
-
-def _array_loop(
-    requests: Sequence[Request],
-    workers: list[Worker],
-    pool: _Pool,
-    pick: _PickFn,
-    *,
-    horizon: float | None,
-    charge_scheduler_overhead: bool,
-    fs: "FaultStateLike | None" = None,
-    res: "ResidencyStateLike | None" = None,
-    wall_budget_s: float = 0.0,
-) -> SimResult:
-    """The array-backed engine behind ``run_event_loop(engine="array")``.
-
-    Identical observable behaviour to the scalar loop — same scheduler-hook
-    call sequence, same timestamps, same rng consumption, same result
-    fields — with the event plumbing swapped out:
-
-    - ARRIVALs never touch a priority queue: the
-      :class:`~repro.core.requeststore.RequestStore` presorts the trace
-      into numpy columns with same-timestamp group boundaries, so the
-      arrival source is a cursor over precomputed slices (the scalar loop
-      pays a heap push **and** pop per request);
-    - DONE/WAKE events live in the :class:`~repro.core.eventwheel.EventWheel`
-      calendar queue and are drained a bucket at a time; a three-way merge
-      (arrival cursor, in-hand bucket batch, wheel head) preserves the
-      scalar loop's global ``(time, seq)`` order, with arrivals numbered
-      ``0..n-1`` before any dynamic event so same-timestamp arrivals still
-      come first;
-    - per-request state writes go to the store's ``started``/``finished``
-      columns via one fancy-indexed write per *batch*, and the end-of-run
-      stats fold is one vectorized pass (the object attributes are still
-      written at event time — schedulers like Clipper read
-      ``req.started``/``req.finished`` inside ``on_batch_done``).
-
-    ``peak_heap_size`` reports peak *pending events*: undelivered arrivals
-    plus wheel occupancy (in-flight DONEs, live and superseded WAKEs) —
-    the satellite fix for the bucketed path, where "Python heap length"
-    no longer exists.
-    """
-    n = len(workers)
-    scored0 = _n_scored(workers)
-    store = RequestStore(requests)
-    reqs = store.requests
-    gstarts = store.group_starts
-    gtimes = store.group_times
-    ng = len(gtimes)
-    n_req = len(reqs)
-    started_col = store.started
-    finished_col = store.finished
-
-    wheel = EventWheel(bucket_ms=_wheel_width(gtimes))
-    # Arrivals conceptually hold seqs 0..n-1 (assigned at store build, in
-    # release order); dynamic events keep counting — so at equal times
-    # arrivals sort first, exactly like the scalar heap's (time, seq) keys.
-    seq = itertools.count(n_req)
-
-    plan = fs.plan if fs is not None else None
-    n_rejected = 0
-    n_failed = 0
-    n_retried = 0
-    n_finished = 0
-    truncated = False
-    down = [False] * n
-    # per-worker crash epoch — see the scalar loop's tombstone comment
-    epoch = [0] * n
-    # in-flight (batch, rows) payloads, maintained only under a fault plan
-    running: list[tuple[Batch, object] | None] = [None] * n
-    gate = plan is not None and plan.admission_floor > 0.0
-    timeout_ms = plan.batch_timeout_ms if plan is not None else 0.0
-    if fs is not None and fs.crashes:
-        # initial crash draws in worker index order — seqs continue from
-        # n_req exactly like the scalar loop's post-arrival pushes
-        for w in range(n):
-            wheel.push(fs.next_crash(w, 0.0), next(seq), _CRASH, w)
-
-    peak_pending = n_req + len(wheel)
-    arr_left = n_req  # arrivals not yet delivered to a scheduler
-    worker_busy_time = 0.0
-    sched_time = 0.0  # wall-clock seconds inside scheduler hooks
-    n_decisions = 0
-    n_batches = 0
-    last_time = 0.0
-    inflight: list[tuple[float, float] | None] = [None] * n  # (start, end)
-    pending_wake: list[float | None] = [None] * n
-    pc = _time.perf_counter
-    delivers = [getattr(w.scheduler, "on_arrivals", None) for w in workers]
-    # Columnar delivery hooks (DESIGN.md §10): a scheduler exposing
-    # ``on_arrivals_cols(store, lo, hi, now)`` takes bulk arrivals as a
-    # store row range instead of an object slice; ``on_arrival_row`` is
-    # the idle-path single-row variant.  Schedulers without them get the
-    # exact object-delivery sequence the scalar loop produces.
-    delivers_cols = [
-        getattr(w.scheduler, "on_arrivals_cols", None) for w in workers
-    ]
-    row_delivers = [
-        getattr(w.scheduler, "on_arrival_row", None) for w in workers
-    ]
-    busy = pool.busy
-    # Schedulers that read ``req.started``/``req.finished`` inside their
-    # hooks (Clipper's AIMD, adaptive Clockwork) declare it via
-    # ``reads_request_state``; unknown schedulers default to True for
-    # safety.  When nobody in the pool reads mid-run state, the loop skips
-    # the two per-request attribute writes on the hot path and flushes the
-    # columns once at the end (``store.writeback()``) instead.
-    live_state = any(
-        getattr(w.scheduler, "reads_request_state", True) for w in workers
-    )
-
-    def try_dispatch(w: int, now: float) -> None:
-        nonlocal worker_busy_time, peak_pending, sched_time, n_decisions
-        if busy[w] or down[w]:
-            return
-        worker = workers[w]
-        # simlint: ignore[R1] -- meters real scheduler overhead (reported, optionally charged as latency); the sim clock itself stays virtual
-        t0 = pc()
-        batch, wake = worker.scheduler.next_batch(now)
-        # simlint: ignore[R1] -- closes the overhead meter opened above
-        dt = pc() - t0
-        sched_time += dt
-        n_decisions += 1
-        overhead = dt * 1e3 if charge_scheduler_overhead else 0.0
-        if batch is not None and getattr(batch, "decode", False):
-            # Resumable token-level execution — the array flavour of the
-            # scalar loop's decode dispatch: identical hook order and
-            # timestamps, with per-batch column writes for ``started``.
-            if fs is not None:
-                raise ValueError(
-                    "decode (token-level) batches are not supported "
-                    "under fault injection"
-                )
-            if res is not None:
-                raise ValueError(
-                    "decode (token-level) batches are not supported "
-                    "under multi-model residency"
-                )
-            start = now + overhead
-            rows = batch.rows
-            if rows is None:
-                # simlint: ignore[R5] -- one row-index list per dispatched decode batch
-                rows = store.rows_for(batch.requests)
-            if type(rows) is range and rows.step == 1:
-                started_col[rows.start:rows.stop] = start
-            else:
-                rows = np.asarray(rows, dtype=np.intp)
-                started_col[rows] = start
-            run = _DecodeRun(
-                batch, list(batch.requests), [int(x) for x in rows]
-            )
-            dur = _decode_step_dur(
-                worker.executor, run.active, batch.requests, start
-            )
-            if pool.track_work:
-                if live_state:
-                    for r in batch.requests:
-                        r.started = start
-                        pool.discharge(w, r.rid)
-                else:
-                    for r in batch.requests:
-                        pool.discharge(w, r.rid)
-            elif live_state:
-                for r in batch.requests:
-                    r.started = start
-            busy[w] = True
-            worker_busy_time += dur
-            inflight[w] = (start, start + dur)
-            wheel.push(start + dur, next(seq), _STEP, (w, run, epoch[w]))
-            pending = arr_left + len(wheel)
-            if pending > peak_pending:
-                peak_pending = pending
-        elif batch is not None:
-            start = now + overhead
-            if res is not None:
-                # Weights residency — charged exactly as in the scalar
-                # loop: same acquire() call order, same stall arithmetic.
-                if batch.model is None:
-                    raise ValueError(
-                        "residency-managed run dispatched a batch without "
-                        "a model id (scheduler must stamp Batch.model)"
-                    )
-                stall = res.acquire(w, batch.model, start)
-                start += stall
-            else:
-                stall = 0.0
-            dur = worker.executor(batch, start)
-            ev_kind = _DONE
-            if fs is not None:
-                dur = fs.straggle(dur)
-                if 0.0 < timeout_ms < dur:
-                    # overlong batch: aborted at the timeout deadline
-                    dur = timeout_ms
-                    ev_kind = _ABORT
-            rows = batch.rows
-            if rows is None:
-                # simlint: ignore[R5] -- one row-index list per dispatched batch: the price of one fancy-indexed column write replacing per-request attribute churn
-                rows = store.rows_for(batch.requests)
-            if type(rows) is range and rows.step == 1:
-                # rows-annotated batch (``on_arrivals_cols`` schedulers):
-                # the column write is an O(1) slice assignment
-                started_col[rows.start:rows.stop] = start
-            else:
-                rows = np.asarray(rows, dtype=np.intp)
-                started_col[rows] = start
-            if pool.track_work:
-                if live_state:
-                    for r in batch.requests:
-                        r.started = start
-                        pool.discharge(w, r.rid)
-                else:
-                    for r in batch.requests:
-                        pool.discharge(w, r.rid)
-            elif live_state:
-                for r in batch.requests:
-                    r.started = start
-            busy[w] = True
-            worker_busy_time += stall + dur
-            inflight[w] = (start - stall, start + dur)
-            if fs is not None:
-                running[w] = (batch, rows)
-            wheel.push(
-                start + dur, next(seq), ev_kind, (w, batch, rows, epoch[w])
-            )
-            pending = arr_left + len(wheel)
-            if pending > peak_pending:
-                peak_pending = pending
-        elif wake is not None and np.isfinite(wake) and wake > now:
-            if pending_wake[w] is None or wake < pending_wake[w]:
-                pending_wake[w] = wake
-                wheel.push(wake, next(seq), _WAKE, w)
-                pending = arr_left + len(wheel)
-                if pending > peak_pending:
-                    peak_pending = pending
-        # the decision may have timed requests out (drop phase) — keep the
-        # policy load signal honest
-        pool.sweep_dropped(w)
-
-    def work_remains() -> bool:
-        # see the scalar loop: crashes only reschedule while unresolved
-        # work exists anywhere, so the wheel can drain
-        resolved = n_finished + n_rejected + n_failed
-        for w_ in workers:
-            resolved += w_.scheduler.n_timed_out  # type: ignore[attr-defined]
-        return resolved < n_req
-
-    def abort_batch(w: int, batch: Batch, rows, now: float) -> None:
-        # Crash/timeout abort, array flavour: clear the started column
-        # for the aborted rows (writeback must not resurrect a phantom
-        # start), then run each request through the retry gate.
-        nonlocal n_failed, n_retried, peak_pending
-        assert fs is not None
-        if type(rows) is range:
-            started_col[rows.start:rows.stop] = np.nan
-        else:
-            started_col[np.asarray(rows, dtype=np.intp)] = np.nan
-        sched = workers[w].scheduler
-        for r in batch.requests:
-            if live_state:
-                r.started = None
-            retry, t_retry = fs.retry_decision(sched, r, now)
-            if retry:
-                r.retries += 1
-                n_retried += 1
-                wheel.push(t_retry, next(seq), _RETRY, r)
-            else:
-                r.failed = now
-                n_failed += 1
-        pending = arr_left + len(wheel)
-        if pending > peak_pending:
-            peak_pending = pending
-
-    wall_deadline = None
-    if wall_budget_s > 0.0:
-        # simlint: ignore[R1] -- wall-budget truncation is real elapsed time by design; the sim clock stays virtual
-        wall_deadline = pc() + wall_budget_s
-    n_events = 0
-    gi = 0  # next arrival group
-    buf: list = []  # in-hand wheel bucket (drained, partially consumed)
-    bi = 0
-    nbuf = 0
-    ev: tuple = ()
-    while True:
-        n_events += 1
-        if (
-            wall_deadline is not None
-            and not n_events & 1023
-            # simlint: ignore[R1] -- wall-budget truncation check (real elapsed time by design)
-            and pc() > wall_deadline
-        ):
-            # Out of wall-clock budget: stop at the last processed event
-            # and clamp busy credit, mirroring the scalar loop.
-            truncated = True
-            for span in inflight:
-                if span is not None and span[1] > last_time:
-                    worker_busy_time -= span[1] - max(span[0], last_time)
-            break
-        # --- three-way merge: arrival cursor vs in-hand bucket vs wheel ---
-        t_arr = gtimes[gi] if gi < ng else math.inf
-        if bi < nbuf:
-            ev = buf[bi]
-            ekey = (ev[0], ev[1])
-            take = _TAKE_BUF
-            if wheel:
-                wkey = wheel.peek_key()
-                if wkey < ekey:
-                    # an event pushed *during* the current bucket batch
-                    # landed before its remaining entries — take it singly
-                    ekey = wkey
-                    take = _TAKE_ONE
-        elif wheel:
-            ekey = wheel.peek_key()
-            take = _TAKE_BUCKET
-        else:
-            ekey = _NO_EVENT
-            take = 0
-        if t_arr <= ekey[0]:
-            if t_arr == math.inf:
-                break  # arrivals, bucket batch and wheel all exhausted
-            now = t_arr
-            if horizon is not None and now > horizon:
-                last_time = horizon
-                for span in inflight:
-                    if span is not None and span[1] > horizon:
-                        worker_busy_time -= span[1] - max(span[0], horizon)
-                break
-            last_time = now
-            a, b = gstarts[gi], gstarts[gi + 1]
-            gi += 1
-            arr_left -= b - a
-            if n == 1:
-                # Single-worker fast path (the benchmark regime): no picks,
-                # no charges.  While the worker is idle its share of the
-                # burst is delivered one request at a time with a dispatch
-                # attempt in between (scalar semantics: an urgent
-                # head-of-burst request can grab the idle worker); the
-                # moment it goes busy the rest of the group is ONE slice
-                # handed to bulk ``on_arrivals`` — no per-request Python at
-                # all, which is where the array engine's throughput lives.
-                sched0 = workers[0].scheduler
-                dr0 = row_delivers[0]
-                if gate:
-                    # Admission-gated single-worker path: per-request
-                    # probes mirror the scalar loop exactly (idle-phase
-                    # delivery with dispatch attempts, then one bulk
-                    # object flush for the admitted busy-phase tail).
-                    # Kept entirely off the fault-free fast path below.
-                    assert fs is not None
-                    # simlint: ignore[R5] -- one admitted-tail buffer per gated burst
-                    held: list[Request] = []
-                    for i in range(a, b):
-                        req = reqs[i]
-                        if not fs.admit(
-                            sched0,
-                            req,
-                            now,
-                            # mirrors the scalar backlog probe: len(held)
-                            # plays pending_offset's role (this path never
-                            # charges the pool)
-                            getattr(sched0, "n_pending", 0)
-                            + len(held)
-                            + (1 if busy[0] else 0),
-                        ):
-                            req.rejected = now
-                            n_rejected += 1
-                            continue
-                        if busy[0]:
-                            held.append(req)
-                            continue
-                        t0 = pc()  # simlint: ignore[R1] -- overhead meter, not sim time
-                        if dr0 is not None:
-                            dr0(store, i, now)
-                        else:
-                            sched0.on_arrival(req, now)
-                        sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
-                        try_dispatch(0, now)
-                    if held:
-                        deliver = delivers[0]
-                        t0 = pc()  # simlint: ignore[R1] -- overhead meter, not sim time
-                        if deliver is not None:
-                            deliver(held, now)
-                        else:
-                            for req in held:
-                                sched0.on_arrival(req, now)
-                        sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
-                    continue
-                i = a
-                while i < b and not busy[0]:
-                    t0 = pc()  # simlint: ignore[R1] -- overhead meter, not sim time
-                    if dr0 is not None:
-                        dr0(store, i, now)
-                    else:
-                        sched0.on_arrival(reqs[i], now)
-                    sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
-                    i += 1
-                    try_dispatch(0, now)
-                if i < b:
-                    dc0 = delivers_cols[0]
-                    deliver = delivers[0]
-                    t0 = pc()  # simlint: ignore[R1] -- overhead meter, not sim time
-                    if dc0 is not None:
-                        # columnar bulk delivery: a row range, no slice
-                        dc0(store, i, b, now)
-                    elif deliver is not None:
-                        # simlint: ignore[R5] -- one slice per (burst, busy) window, replacing per-request heap pops and scheduler calls
-                        deliver(reqs[i:b], now)
-                    else:
-                        for req in reqs[i:b]:
-                            sched0.on_arrival(req, now)
-                    sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
-            else:
-                # Multi-worker: route/deliver in arrival order, exactly as
-                # the scalar loop does (same pick → same rng draws, same
-                # charge/busy side-effect ordering, same bulk flush per
-                # busy worker).
-                # simlint: ignore[R5] -- one routing buffer per burst, replacing per-request scheduler calls with one bulk delivery per worker
-                buffered: dict[int, list[Request]] = {}
-                for i in range(a, b):
-                    req = reqs[i]
-                    w = pick(req, now, pool)
-                    if gate and not fs.admit(
-                        workers[w].scheduler,
-                        req,
-                        now,
-                        getattr(workers[w].scheduler, "n_pending", 0)
-                        + pool.pending_offset[w]
-                        + (1 if busy[w] else 0),
-                    ):
-                        # shed at the front door (pick already consumed
-                        # its rng draws — same stream with the gate off)
-                        req.rejected = now
-                        n_rejected += 1
-                        continue
-                    pool.charge(w, req)
-                    if busy[w]:
-                        # simlint: ignore[R5] -- group list created once per (burst, worker), not per request
-                        buffered.setdefault(w, []).append(req)
-                        pool.pending_offset[w] += 1
-                    else:
-                        t0 = pc()  # simlint: ignore[R1] -- overhead meter, not sim time
                         workers[w].scheduler.on_arrival(req, now)
-                        sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
-                        try_dispatch(w, now)
-                for w, group in buffered.items():
-                    pool.pending_offset[w] = 0
-                    deliver = delivers[w]
-                    t0 = pc()  # simlint: ignore[R1] -- overhead meter, not sim time
-                    if deliver is not None:
-                        deliver(group, now)
-                    else:
-                        sched = workers[w].scheduler
-                        for req in group:
-                            sched.on_arrival(req, now)
-                    sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
-            continue
-        # --- dynamic event (DONE/WAKE) ---
-        if take == _TAKE_BUF:
-            now, _s, kind, payload = ev
-            bi += 1
-        elif take == _TAKE_BUCKET:
-            # refill the in-hand batch with the next wheel bucket — the
-            # batched DONE/WAKE path: one calendar-bucket drain amortizes
-            # the queue maintenance over every event in the bucket
-            buf = wheel.pop_bucket()
-            bi = 1
-            nbuf = len(buf)
-            now, _s, kind, payload = buf[0]
-        else:  # _TAKE_ONE
-            now, _s, kind, payload = wheel.pop()
-        if horizon is not None and now > horizon:
-            last_time = horizon
-            for span in inflight:
-                if span is not None and span[1] > horizon:
-                    worker_busy_time -= span[1] - max(span[0], horizon)
-            break
-        last_time = now
-        if kind == _DONE:
+                sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
+        elif kind == _DONE:
             w, batch, rows, ep = payload
             if ep != epoch[w]:
                 continue  # tombstone: the worker crashed under this batch
@@ -1641,56 +1241,35 @@ def _array_loop(
                 running[w] = None
             n_batches += 1
             n_finished += len(batch.requests)
-            if type(rows) is range:
-                finished_col[rows.start:rows.stop] = now
-                alone = store.true_time[rows.start:rows.stop].tolist()
-            else:
-                finished_col[rows] = now
-                # simlint: ignore[R5] -- one alone-times list per completed batch (feedback path), not per request
-                alone = store.true_time[rows].tolist()
-            if live_state:
-                for r in batch.requests:
-                    r.finished = now
+            finished(batch.requests, rows, now)
+            alone = src.alone(batch.requests, rows)
             t0 = pc()  # simlint: ignore[R1] -- overhead meter, not sim time
             workers[w].scheduler.on_batch_done(batch, now, alone)
             sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
             try_dispatch(w, now)
         elif kind == _STEP:
-            # One decode iteration — mirrors the scalar loop's handler
-            # exactly (same hook order, same timestamps), with ``finished``
-            # landing in the store column per step instead of per object.
+            # One decode iteration of a resumable execution: advance token
+            # counts, retire EOS requests, let the scheduler admit joiners
+            # at this token boundary, then re-arm (or drain the run).
             w, run, ep = payload
             if ep != epoch[w]:
-                continue  # tombstone (kept uniform with _DONE)
-            finished, fin_rows = _advance_decode(run, now)
-            n_finished += len(finished)
-            if fin_rows:
-                finished_col[np.asarray(fin_rows, dtype=np.intp)] = now
-            if live_state:
-                for r in finished:
-                    r.finished = now
+                continue  # tombstone (decode runs never coexist with faults today, but keep the contract uniform)
+            done, done_rows = _advance_decode(run, now)
+            n_finished += len(done)
+            finished(done, done_rows, now)
             t0 = pc()  # simlint: ignore[R1] -- overhead meter, not sim time
             joined = workers[w].scheduler.on_decode_step(
-                finished, len(run.active), now
+                done, len(run.active), now
             )
             sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
             n_decisions += 1
             if joined:
-                # simlint: ignore[R5] -- one row-index list per join group
-                jrows = store.rows_for(joined)
-                started_col[np.asarray(jrows, dtype=np.intp)] = now
-                run.rows.extend(int(x) for x in jrows)
-                if pool.track_work:
-                    if live_state:
-                        for r in joined:
-                            r.started = now
-                            pool.discharge(w, r.rid)
-                    else:
-                        for r in joined:
-                            pool.discharge(w, r.rid)
-                elif live_state:
+                rows = started(joined, None, now)
+                if rows is not None:
+                    run.rows.extend(int(x) for x in rows)
+                if track_work:
                     for r in joined:
-                        r.started = now
+                        pool.discharge(w, r.rid)
                 run.active.extend(joined)
             if run.active:
                 dur = _decode_step_dur(
@@ -1698,10 +1277,7 @@ def _array_loop(
                 )
                 worker_busy_time += dur
                 inflight[w] = (now, now + dur)
-                wheel.push(now + dur, next(seq), _STEP, (w, run, ep))
-                pending = arr_left + len(wheel)
-                if pending > peak_pending:
-                    peak_pending = pending
+                push(now + dur, _STEP, (w, run, ep))
             else:
                 n_batches += 1
                 busy[w] = False
@@ -1726,12 +1302,16 @@ def _array_loop(
         elif kind == _CRASH:
             w = payload
             if work_remains():
-                # see the scalar loop: epoch bump tombstones the pending
-                # DONE/ABORT, the in-flight batch aborts, restart follows
+                # Kill the worker: bump its epoch (outstanding DONE/ABORT
+                # events become tombstones), abort any in-flight batch,
+                # schedule the restart.  With no work left the crash is
+                # discarded and nothing is rescheduled, so the queue
+                # drains and the loop terminates.
                 epoch[w] += 1
                 down[w] = True
                 span = inflight[w]
                 if span is not None:
+                    # credit only the work actually done before the crash
                     worker_busy_time -= span[1] - max(span[0], now)
                     inflight[w] = None
                     busy[w] = False
@@ -1739,26 +1319,21 @@ def _array_loop(
                     running[w] = None
                     assert doomed is not None
                     abort_batch(w, doomed[0], doomed[1], now)
-                wheel.push(
-                    now + plan.restart_delay_ms, next(seq), _RESTART, w
-                )
-                pending = arr_left + len(wheel)
-                if pending > peak_pending:
-                    peak_pending = pending
+                push(now + plan.restart_delay_ms, _RESTART, w)
         elif kind == _RESTART:
             w = payload
             down[w] = False
             if work_remains():
-                wheel.push(fs.next_crash(w, now), next(seq), _CRASH, w)
-                pending = arr_left + len(wheel)
-                if pending > peak_pending:
-                    peak_pending = pending
+                push(fs.next_crash(w, now), _CRASH, w)
             try_dispatch(w, now)
         else:  # _RETRY
             req = payload
             w = pick(req, now, pool) if n > 1 else 0
             if down[w]:
-                # dead-target re-route — see the scalar loop
+                # Dead-target re-route: deterministically drain to the
+                # next live sibling (fleet mode — a dead pool's requeued
+                # work flows across pool boundaries).  All-dead keeps the
+                # original target: it queues and the restart drains it.
                 for k in range(1, n):
                     w2 = (w + k) % n
                     if not down[w2]:
@@ -1770,23 +1345,9 @@ def _array_loop(
             sched_time += pc() - t0  # simlint: ignore[R1] -- overhead meter, not sim time
             try_dispatch(w, now)
 
-    if not live_state:
-        # Mid-run object writes were skipped — flush the state columns
-        # onto the Request objects so callers see the scalar loop's exact
-        # post-run per-object state.
-        store.writeback()
-    # Drop-free fast path: every ``req.dropped = ...`` write in the repo's
-    # schedulers is paired with an ``n_timed_out`` increment, so a pool
-    # whose schedulers all expose the counter at zero provably dropped
-    # nothing and the O(n) per-object dropped scan can be skipped.
-    no_drops = all(
-        getattr(w_.scheduler, "n_timed_out", None) == 0 for w_ in workers
-    )
-    ok, late, dropped, unserved, lat = store.fold_stats(
-        no_drops=no_drops, n_off_ledger=n_rejected + n_failed
-    )
+    ok, late, dropped, unserved, lat = src.fold(n_rejected + n_failed)
     return SimResult(
-        n_total=n_req,
+        n_total=n_total,
         n_finished_ok=ok,
         n_finished_late=late,
         n_dropped=dropped,
@@ -1795,7 +1356,7 @@ def _array_loop(
         makespan_ms=last_time,
         latencies=lat,
         n_workers=n,
-        peak_heap_size=peak_pending,
+        peak_heap_size=src.peak,
         sched_time_ms=sched_time * 1e3,
         n_decisions=n_decisions,
         n_batches=n_batches,
@@ -1808,6 +1369,13 @@ def _array_loop(
         model_load_ms=res.load_ms_total if res is not None else 0.0,
         n_scored=_n_scored(workers) - scored0,
     )
+
+
+def _n_scored(workers: Sequence[Worker]) -> int:
+    """The ``n_scored`` counters of the pool's schedulers, each scheduler
+    once (0 for a scheduler without the counter)."""
+    scheds = {id(w.scheduler): w.scheduler for w in workers}
+    return sum(getattr(s, "n_scored", 0) for s in scheds.values())
 
 
 def simulate(
